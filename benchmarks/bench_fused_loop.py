@@ -234,6 +234,9 @@ def main(n=20_000, k=8, n_queries=512, reps=3) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

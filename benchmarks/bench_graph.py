@@ -182,6 +182,9 @@ def main(n=4_000, k=8, n_shards=8, eps_quantile=60.0) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

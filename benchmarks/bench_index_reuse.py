@@ -98,6 +98,9 @@ def main(n=20_000, n_batches=4, batch_size=512, k=8) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

@@ -310,6 +310,9 @@ def main(n=6000, k=8, storm_n=1200, storm_ops=48, checkpoints=4,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

@@ -20,9 +20,10 @@ gates at bench scale and records them in the summary for CI:
 The monolith and both fabrics use the trueknn engine (the repo default,
 and the engine whose float forms the placed path reproduces exactly —
 the brute oracle's range distances differ at the ULP level).  Runs on
-whatever device count the process booted with (CI forces
-``--xla_force_host_platform_device_count=8``; the module entry point
-forces it too when run standalone).
+whatever device count the process booted with: the chips of a TPU host,
+or on CPU the host devices that
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` creates (CI sets
+it).
 
 Emits CSV rows via the harness contract and returns a summary dict that
 benchmarks/run.py serializes to BENCH_placement.json.
@@ -184,15 +185,10 @@ def main(n=20_000, k=8, n_queries=512, n_shards=8, reps=3) -> dict:
 
 
 if __name__ == "__main__":
-    import os
-
-    # the XLA backend initializes on first use, not import, so setting
-    # the flag here (before any computation has run) still takes effect
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
     import json
+
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print(json.dumps(main(), indent=2, default=str))

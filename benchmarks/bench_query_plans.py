@@ -112,6 +112,9 @@ def main(n=16_000, n_queries=512, k=8) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

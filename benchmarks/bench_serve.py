@@ -130,6 +130,9 @@ def main(n=16_000, k=8, requests_per_load=192,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
 
     print(json.dumps(main(), indent=2, default=str))

@@ -17,6 +17,9 @@ def _section(title):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_brute,
         bench_dataset_size,

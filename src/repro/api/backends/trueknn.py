@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.brute import brute_knn_engine
-from repro.core.fixed_radius import fixed_radius_round
+from repro.core.fixed_radius import CHUNK_CANDIDATES, fixed_radius_round
 from repro.core.fused_loop import build_schedule, fused_search
 from repro.core.grid import _next_pow2, build_grid
 from repro.core.result import KNNResult, RoundStats
@@ -217,6 +217,20 @@ class TrueKNNIndex(NeighborIndex):
             self._sampled_r = sample_start_radius(self._pts, seed=self._seed)
         return self._sampled_r, "sampled"
 
+    def _grid_no_better_than_brute(self, grid, stop_radius,
+                                   cap_exact: bool) -> bool:
+        """True when a round on ``grid`` gathers more candidate slots per
+        query than one query chunk may hold (``CHUNK_CANDIDATES``) and no
+        fewer than the cloud has points: such a round cannot be tiled and
+        does no less work than the exact brute tail, so the schedule ends
+        there and the tail finishes the unresolved queries.  Not under a
+        plain ``stop_radius``: its tails keep the partial lists of the last
+        round inside the radius, which the unbounded tail cannot give."""
+        if stop_radius is not None and not cap_exact:
+            return False
+        slots = 3**self.dim * grid.cap
+        return slots > CHUNK_CANDIDATES and slots >= self.n_points
+
     # -- the hot path ------------------------------------------------------
 
     def plan_details(self, spec, metric: Metric) -> tuple:
@@ -386,6 +400,9 @@ class TrueKNNIndex(NeighborIndex):
             t0 = time.perf_counter()
             grid, hit = self._grid_for(r)
             t_build += 0.0 if hit else time.perf_counter() - t0
+            if self._grid_no_better_than_brute(grid, stop_radius, cap_exact):
+                force_brute_tail = True
+                break
 
             m = alive.size
             if queries is None and m == q_total:

@@ -62,8 +62,6 @@ def make_distributed_knn(
                (-1 = no exclusion) — sharded with queries.
     Returns (d2 (Q, k), idx (Q, k) global indices, counts (Q,)).
     """
-    from jax.experimental.shard_map import shard_map
-
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     p_size = mesh.shape[point_axis]
     assert p_size & (p_size - 1) == 0, "hypercube merge wants pow2 shards"
@@ -99,12 +97,12 @@ def make_distributed_knn(
         return d2, idx, cnt
 
     qspec = P(batch_axes or None, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(point_axis, None), qspec, P(batch_axes or None)),
         out_specs=(qspec, qspec, P(batch_axes or None)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -373,9 +371,7 @@ class PlacedFabric:
                 lambda b, n, v: one_slot(b, n, v, q, thr[0, 0])
             )(blocks, nvalid, vmask)
 
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -387,7 +383,7 @@ class PlacedFabric:
             ),
             out_specs=(P(axis, None, None), P(axis, None, None),
                        P(axis, None)),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 
@@ -553,16 +549,14 @@ class PlacedFabric:
                 lambda c: (c[4] < max_rounds) & jnp.any(c[2]), body, init
             )
             # replicated results leave through a tiled leading slot axis
-            # (check_rep=False: out_specs must mention the mesh axis);
+            # (check_vma=False: out_specs must mention the mesh axis);
             # the host wrapper takes [0]
             return (
                 pool_d[None], pool_i[None], res_round[None], radii[None],
                 jnp.reshape(t, (1,)),
             )
 
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -582,7 +576,7 @@ class PlacedFabric:
                 P(axis, None, None), P(axis, None, None),
                 P(axis, None), P(axis, None), P(axis),
             ),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 

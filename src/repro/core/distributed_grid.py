@@ -92,8 +92,6 @@ def make_grid_round(mesh: Mesh, k: int, table_size: int, *, chunk: int = 1024,
        query_ids (Q,), r2 ()) ->
        (d2 (Q,k), idx (Q,k) global, found (Q,), tests ())
     """
-    from jax.experimental.shard_map import shard_map
-
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     p_size = mesh.shape[point_axis]
     assert p_size & (p_size - 1) == 0
@@ -139,13 +137,13 @@ def make_grid_round(mesh: Mesh, k: int, table_size: int, *, chunk: int = 1024,
 
     qspec = P(batch_axes or None, None)
     gspec = P(point_axis)  # leading shard dim
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(gspec, gspec, gspec, gspec, gspec, gspec,
                   qspec, P(batch_axes or None), P()),
         out_specs=(qspec, qspec, P(batch_axes or None), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

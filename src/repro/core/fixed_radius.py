@@ -24,7 +24,21 @@ import numpy as np
 
 from .grid import Grid, stencil_offsets
 
-__all__ = ["fixed_radius_knn", "fixed_radius_round"]
+__all__ = ["fixed_radius_knn", "fixed_radius_round", "round_chunk",
+           "CHUNK_CANDIDATES"]
+
+# Candidate slots (queries x 3^d x cap) one query chunk gathers at once.
+# The round's temporaries grow with it, and so does the TPU compiler's time
+# for the round, while the gathers' throughput does not: a v5e ran cap-512
+# rounds no faster at 4x this many slots per chunk.
+CHUNK_CANDIDATES = 1 << 20
+
+
+def round_chunk(chunk: int, d: int, cap: int) -> int:
+    """Query chunk for a grid round: ``chunk`` capped so one chunk gathers
+    at most ``CHUNK_CANDIDATES`` slots (a power of two, at least 1)."""
+    fit = max(1, CHUNK_CANDIDATES // (3**d * cap))
+    return int(min(chunk, 1 << (fit.bit_length() - 1)))
 
 
 def _pad_points(points: jax.Array) -> jax.Array:
@@ -158,7 +172,7 @@ def fixed_radius_round(
     q = jnp.asarray(queries, jnp.float32)
     qid = jnp.asarray(query_ids, jnp.int32)
     q_total = q.shape[0]
-    chunk = int(min(chunk, max(1, q_total)))
+    chunk = round_chunk(min(chunk, max(1, q_total)), q.shape[1], grid.cap)
     pad = (-q_total) % chunk
     if pad:
         q = jnp.concatenate([q, jnp.full((pad, q.shape[1]), jnp.inf, q.dtype)])

@@ -15,11 +15,14 @@ jitted program:
 * ``fused_search`` runs one ``jax.lax.while_loop`` whose carry holds the
   per-query best-k heap, an on-device unresolved mask, per-round test
   counters and the resolution round per query.  The predicate reduces the
-  unresolved mask *on device*; each round body is the same
-  ``_chunk_candidates`` scan the per-round host driver traces, selected by
+  unresolved mask *on device*; each round body runs the same
+  ``_chunk_candidates`` the per-round host driver traces, selected by
   ``lax.switch`` over the deduped per-grid branches, with the squared
-  radius as traced data.  An optional brute tail (``_brute_impl``, the
-  exact oracle) runs under ``lax.cond`` only if queries remain unresolved.
+  radius as traced data.  A round visits only the chunks that hold
+  unresolved rows (they are ordered first), in chunks sized to the grid's
+  cap (``round_chunk``), so late rounds cost what their survivors cost.
+  An optional brute tail (``_brute_impl``, the exact oracle) runs over
+  the rows still unresolved, the same way.
 
 Because the loop body and the tail call the *same* jitted subroutines as
 the host driver on the same operands, answers are bit-identical to the
@@ -43,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .brute import _brute_impl
-from .fixed_radius import _chunk_candidates, _pad_points
+from .fixed_radius import _chunk_candidates, _pad_points, round_chunk
 from .grid import _next_pow2, stencil_offsets
 
 __all__ = ["FusedSchedule", "FusedResult", "build_schedule", "fused_search"]
@@ -133,6 +136,9 @@ def build_schedule(index, r0: float, *, stop_radius=None,
             elif r > stop_radius:
                 break
         grid, hit = index._grid_for(r)
+        if index._grid_no_better_than_brute(grid, stop_radius, cap_exact):
+            force_brute_tail = True
+            break
         radii.append(r)
         grids.append(grid)
         hits.append(hit)
@@ -162,6 +168,22 @@ def build_schedule(index, r0: float, *, stop_radius=None,
     )
 
 
+def _over_rows(rows_mask, chunk: int, fn, init):
+    """Visit only the rows set in ``rows_mask``, ``chunk`` rows at a time:
+    the set rows are ordered first (stably, in row order) and a dynamic
+    trip count covers just the chunks that hold them.  ``fn(rows, live,
+    state)`` gets each chunk's row ids and which of them are set; rows of
+    the last chunk past the set ones must be left untouched."""
+    order = jnp.argsort(~rows_mask, stable=True).astype(jnp.int32)
+    n_chunks = (jnp.sum(rows_mask, dtype=jnp.int32) + chunk - 1) // chunk
+
+    def body(i, state):
+        rows = jax.lax.dynamic_slice(order, (i * chunk,), (chunk,))
+        return fn(rows, rows_mask[rows], state)
+
+    return jax.lax.fori_loop(0, n_chunks, body, init)
+
+
 @lru_cache(maxsize=None)
 def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
               k: int, chunk: int, tail_chunk: int):
@@ -180,53 +202,47 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
         d = pts_padded.shape[1]
         q_pad = q.shape[0]
         offs = jnp.asarray(stencil_offsets(d))
-        qs = q.reshape(-1, chunk, d)
-        qids = qid.reshape(-1, chunk)
 
         def make_branch(b):
             buckets, point_cells, origin, inv_cell, res_arr = grids[b]
             table_size = branch_tables[b]
+            cb = round_chunk(chunk, d, buckets.shape[1])
 
             def branch(carry):
                 best_d2, best_i, found, unres, res_round, tests_vec, t = carry
                 r2 = r2s[t]
 
-                def one_chunk(c, inp):
-                    qc, qidc, uc = inp
+                def one_chunk(rows, live, state):
+                    bd, bi, fd, tests = state
                     top_d2, top_i, fnd, valid = _chunk_candidates(
                         pts_padded, buckets, point_cells, origin, inv_cell,
-                        res_arr, offs, qc, qidc, r2,
+                        res_arr, offs, q[rows], qid[rows], r2,
                         table_size=table_size, k=k,
                     )
                     # only still-unresolved rows are charged (resolved and
                     # padding rows never reach the host driver's kernel)
-                    tests = jnp.sum(valid & uc[:, None], dtype=jnp.float32)
-                    return c, (top_d2, top_i, fnd, tests)
+                    tests = tests + jnp.sum(
+                        valid & live[:, None], dtype=jnp.float32
+                    )
+                    # REPLACE (not merge) for unresolved rows: every round
+                    # re-searches from scratch at the larger radius, exactly
+                    # like the host driver's per-round overwrite
+                    bd = bd.at[rows].set(
+                        jnp.where(live[:, None], top_d2, bd[rows])
+                    )
+                    bi = bi.at[rows].set(
+                        jnp.where(live[:, None], top_i, bi[rows])
+                    )
+                    fd = fd.at[rows].set(jnp.where(live, fnd, fd[rows]))
+                    return bd, bi, fd, tests
 
-                u_ch = unres.reshape(-1, chunk)
-                if qs.shape[0] == 1:
-                    # single-chunk batch: skip the scan machinery — its
-                    # per-iteration stacking is measurable per round on
-                    # the small-batch serving shape
-                    _, (td, ti, fc, tc) = one_chunk(
-                        None, (qs[0], qids[0], u_ch[0])
-                    )
-                else:
-                    _, (td, ti, fc, tc) = jax.lax.scan(
-                        one_chunk, None, (qs, qids, u_ch)
-                    )
-                td = td.reshape(q_pad, k)
-                ti = ti.reshape(q_pad, k)
-                fc = fc.reshape(q_pad)
-                # REPLACE (not merge) for unresolved rows: every round
-                # re-searches from scratch at the larger radius, exactly
-                # like the host driver's per-round overwrite
-                best_d2 = jnp.where(unres[:, None], td, best_d2)
-                best_i = jnp.where(unres[:, None], ti, best_i)
-                found = jnp.where(unres, fc, found)
-                res_now = unres & (fc >= k)
+                best_d2, best_i, found, tests = _over_rows(
+                    unres, cb, one_chunk,
+                    (best_d2, best_i, found, jnp.float32(0)),
+                )
+                res_now = unres & (found >= k)
                 res_round = jnp.where(res_now, t, res_round)
-                tests_vec = tests_vec.at[t].set(jnp.sum(tc))
+                tests_vec = tests_vec.at[t].set(tests)
                 return (best_d2, best_i, found, unres & ~res_now,
                         res_round, tests_vec, t + jnp.int32(1))
 
@@ -255,23 +271,25 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
         best_d = jnp.sqrt(best_d2)
         if has_tail:
             # exact oracle for whatever the loop left unresolved, inlined
-            # into the same program (jit-of-jit): identical ops to the
-            # host driver's brute_knn_engine tail.  Rows are replaced
-            # wholesale, as the host does; the hybrid re-cut and the
-            # found recount are host-side post-filters in both drivers.
-            def with_tail(args):
-                bd_, bi_ = args
+            # into the same program (jit-of-jit) over just those rows:
+            # identical ops to the host driver's brute_knn_engine tail.
+            # Rows are replaced wholesale, as the host does; the hybrid
+            # re-cut and the found recount are host-side post-filters in
+            # both drivers.
+            def tail_chunk_fn(rows, live, state):
+                bd_, bi_ = state
                 d2t, it = _brute_impl(
-                    pts_padded[:n], q, qid, k=k, chunk=tail_chunk,
-                    exclude_self=True, metric="l2",
+                    pts_padded[:n], q[rows], qid[rows], k=k,
+                    chunk=tail_chunk, exclude_self=True, metric="l2",
                 )
-                dt = jnp.sqrt(d2t)
-                bd_ = jnp.where(unres[:, None], dt, bd_)
-                bi_ = jnp.where(unres[:, None], it, bi_)
+                bd_ = bd_.at[rows].set(
+                    jnp.where(live[:, None], jnp.sqrt(d2t), bd_[rows])
+                )
+                bi_ = bi_.at[rows].set(jnp.where(live[:, None], it, bi_[rows]))
                 return bd_, bi_
 
-            best_d, best_i = jax.lax.cond(
-                jnp.any(unres), with_tail, lambda a: a, (best_d, best_i)
+            best_d, best_i = _over_rows(
+                unres, tail_chunk, tail_chunk_fn, (best_d, best_i)
             )
         return best_d, best_i, found, unres, res_round, tests_vec, t
 

@@ -87,7 +87,9 @@ def hash_coords(coords, table_size: int):
         h = u[..., 0] * jnp.uint32(_HASH_PRIMES[0])
         for a in range(1, coords.shape[-1]):
             h = h ^ (u[..., a] * jnp.uint32(_HASH_PRIMES[a]))
-        return (h & jnp.uint32(table_size - 1)).astype(jnp.int32)
+        # table_size may be traced (one sort compile for every grid shape)
+        mask = jnp.asarray(table_size - 1).astype(jnp.uint32)
+        return (h & mask).astype(jnp.int32)
     u = coords.astype(np.uint32)
     h = u[..., 0] * np.uint32(_HASH_PRIMES[0])
     for a in range(1, coords.shape[-1]):
@@ -101,12 +103,15 @@ def cell_coords_of(points, origin, inv_cell, res_arr):
     return jnp.clip(c, 0, res_arr - 1)
 
 
-@partial(jax.jit, static_argnames=("table_size", "cap", "n_valid"))
-def _bin_points(points, origin, inv_cell, res_arr, *, table_size, cap, n_valid):
-    """Counting-sort points into hash buckets (jit, static shapes).
+@jax.jit
+def _bucket_order(points, origin, inv_cell, res_arr, table_size, n_valid):
+    """Stable order of the points by hash bucket, the sorted bucket ids, and
+    the points' cell coords.
 
     Rows >= n_valid are padding (sharded grids pad shards to equal length):
-    they are never binned and their cell coords are -2 (match nothing).
+    they sort last and their cell coords are -2 (match nothing).  The table
+    size and n_valid are traced, so the one sort of the cloud compiles once
+    for every grid over it (a TPU compile of a 2^20-row sort takes ~20 s).
     """
     n = points.shape[0]
     valid = jnp.arange(n) < n_valid
@@ -114,21 +119,33 @@ def _bin_points(points, origin, inv_cell, res_arr, *, table_size, cap, n_valid):
         jnp.where(jnp.isfinite(points), points, 0.0), origin, inv_cell, res_arr
     )
     h = jnp.where(valid, hash_coords(coords, table_size), table_size - 1)
-    order = jnp.argsort(h)  # stable
-    sorted_h = h[order]
-    counts = jnp.bincount(jnp.where(valid, h, table_size), length=table_size)
-    starts = jnp.cumsum(counts) - counts
-    slot = jnp.arange(n) - starts[sorted_h]  # rank within own bucket
-    keep = (slot < cap) & (order < n_valid)
-    buckets = jnp.full((table_size, cap), n, dtype=jnp.int32)
-    buckets = buckets.at[
-        jnp.where(keep, sorted_h, table_size),  # OOB row -> dropped
-        jnp.clip(slot, 0, cap - 1),
-    ].set(order.astype(jnp.int32), mode="drop")
+    order = jnp.argsort(h).astype(jnp.int32)  # stable
     coords = jnp.where(valid[:, None], coords, -2)
     sentinel = jnp.full((1, points.shape[1]), -2, jnp.int32)
     point_cells = jnp.concatenate([coords, sentinel], axis=0)
-    return buckets, point_cells
+    return order, h[order], point_cells
+
+
+@partial(jax.jit, static_argnames=("table_size", "cap"))
+def _fill_buckets(order, sorted_h, n_valid, *, table_size, cap):
+    """Lay the bucket-sorted points into ``(table_size, cap)`` slots, and
+    return the fullest bucket's population, so the caller can prove no
+    point was dropped for want of a slot."""
+    n = order.shape[0]
+    i = jnp.arange(n, dtype=jnp.int32)
+    # rank within own bucket: distance from the bucket's first sorted row
+    slot = i - jnp.searchsorted(sorted_h, sorted_h, side="left").astype(
+        jnp.int32
+    )
+    real = i < n_valid  # the stable sort put the padding rows last
+    # ascending and unique while every bucket fits its cap; padding rows
+    # land past the table and are dropped
+    pos = jnp.where(real, sorted_h * cap + slot, table_size * cap + i)
+    buckets = jnp.full((table_size * cap,), n, jnp.int32).at[pos].set(
+        order, mode="drop", indices_are_sorted=True, unique_indices=True
+    )
+    fullest = jnp.max(jnp.where(real, slot, -1)) + 1
+    return buckets.reshape(table_size, cap), fullest
 
 
 def build_grid(
@@ -182,8 +199,12 @@ def build_grid(
     else:
         while True:
             cell = (extent / res).astype(np.float32)
+            # the device's float32 arithmetic (cell_coords_of): dividing
+            # instead moves boundary points to the next cell, and a bucket
+            # the probe undercounts would overflow its cap
             coords = np.clip(
-                np.floor((pts - lo) / cell).astype(np.int64), 0, res - 1
+                np.floor((pts - lo) * (np.float32(1) / cell)).astype(np.int64),
+                0, res - 1,
             )
             # pack to a unique id per occupied cell (host side, exact)
             packed = coords[:, 0]
@@ -193,9 +214,20 @@ def build_grid(
             table_size = force_table_size or _next_pow2(
                 max(int(n_occ / load_factor), 16)
             )
-            h = hash_coords(coords.astype(np.int64), table_size)
-            occ = np.bincount(h, minlength=table_size)
-            needed_cap = _next_pow2(max(int(occ.max()), 1))
+            while True:
+                h = hash_coords(coords.astype(np.int64), table_size)
+                occ = np.bincount(h, minlength=table_size)
+                needed_cap = _next_pow2(max(int(occ.max()), 1))
+                # Over the bucket budget, fold the table before coarsening:
+                # the fullest cell sets the cap, and the exact coord match
+                # filters the extra collisions, so halving the table halves
+                # the buckets while the cap barely moves.  Coarsening instead
+                # multiplies every cell's population, and on a skewed cloud
+                # of 10^6 points it collapsed the grid to a few cells.
+                if (force_table_size or table_size <= 16
+                        or table_size * needed_cap <= max_bucket_elems):
+                    break
+                table_size //= 2
             if force_cap:
                 # caller pre-computed a shared shape (sharded-grid stacking);
                 # it must be adequate — exactness over silent truncation.
@@ -214,12 +246,19 @@ def build_grid(
 
     res_t = tuple(int(r) for r in res)
     origin = jnp.asarray(lo)
-    inv_cell = jnp.asarray(1.0 / cell)
+    inv_cell = jnp.asarray(np.float32(1) / cell)
     res_arr = jnp.asarray(res_t, jnp.int32)
-    buckets, point_cells = _bin_points(
-        jnp.asarray(pts_all), origin, inv_cell, res_arr,
-        table_size=table_size, cap=cap, n_valid=n_valid,
+    order, sorted_h, point_cells = _bucket_order(
+        jnp.asarray(pts_all), origin, inv_cell, res_arr, table_size, n_valid
     )
+    buckets, fullest = _fill_buckets(
+        order, sorted_h, n_valid, table_size=table_size, cap=cap
+    )
+    if int(fullest) > cap:
+        raise RuntimeError(
+            f"build_grid: a bucket holds {int(fullest)} points but the probe "
+            f"sized buckets for {cap}; binning would drop points"
+        )
     return Grid(
         buckets=buckets,
         point_cells=point_cells,

@@ -1,9 +1,10 @@
 """User-facing jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` —
-Pallas's Python interpreter — which validates the kernel body bit-for-bit
-against the BlockSpec pipeline it would run on TPU.  On TPU backends the same
-call compiles to Mosaic.
+On a TPU backend the kernels compile to Mosaic.  On the CPU backend (tests,
+CI) they execute with ``interpret=True`` — Pallas's Python interpreter —
+which runs the same kernel body over the same BlockSpec pipeline.  Any other
+backend raises: there is no silent fallback to the interpreter or to the jnp
+reference.
 """
 
 from __future__ import annotations
@@ -12,13 +13,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .pairwise_topk import DEFAULT_TP, DEFAULT_TQ, pairwise_topk_padded
+from .pairwise_topk import DEFAULT_TP, pairwise_topk_padded, query_tile
 
 __all__ = ["pairwise_topk", "l2_normalize"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """False on TPU (Mosaic), True on CPU (the Pallas interpreter); any
+    other backend has no kernel path and raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"pairwise_topk: no Pallas kernel path on backend {backend!r} "
+        "(TPU compiles it, CPU interprets it)"
+    )
 
 
 def _round_up(x: int, m: int) -> int:
@@ -44,7 +55,6 @@ def pairwise_topk(
     metric: str = "l2",
     tq: int | None = None,
     tp: int | None = None,
-    interpret: bool | None = None,
 ):
     """Exact k smallest distances from each query to the point set, plus the
     count of points within ``radius`` — fused, streaming, O(Q·k) output
@@ -66,8 +76,7 @@ def pairwise_topk(
     n_q, d = q.shape
     n_real = p.shape[0]
     assert p.shape[1] == d
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret()
 
     r = float(radius)
     if metric == "cosine":
@@ -86,9 +95,10 @@ def pairwise_topk(
     else:
         raise ValueError(f"pairwise_topk: unsupported metric {metric!r}")
 
-    tq = tq or min(DEFAULT_TQ, _round_up(n_q, 8))
+    tq = tq or min(query_tile(int(k)), _round_up(n_q, 8))
     tp = tp or min(DEFAULT_TP, _round_up(n_real, 128))
-    dp = _round_up(max(d, 1), 128 if _on_tpu() else 8)  # lane-align features
+    # lane-align features on TPU; the interpreter only needs sublane width
+    dp = _round_up(max(d, 1), 8 if interpret else 128)
 
     qp = _round_up(n_q, tq)
     np_pad = _round_up(n_real, tp)
@@ -110,7 +120,7 @@ def pairwise_topk(
         n_real=int(n_real),
         tq=tq,
         tp=tp,
-        interpret=bool(interpret),
+        interpret=interpret,
         metric=kernel_metric,
         n_dim=d,
     )

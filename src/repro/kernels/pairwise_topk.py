@@ -40,9 +40,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TQ = 256
 DEFAULT_TP = 512
+# The unrolled selection network keeps O(TQ * (k + TP)) live values in
+# VMEM, so the query tile shrinks as k grows to stay inside the 16 MiB
+# scoped-VMEM default: TQ * k <= 8192 (v5e compiles k=32 at TQ=256, k=64
+# at 128, k=128 at 64, k=256 at 32, all at TP=512 over 2^20 points).
+_TQ_TIMES_K = 8192
+MAX_K = 256  # largest k rehearsed to compile for v5e
 
 _NEG_LARGE = -jnp.inf
 
@@ -102,13 +109,14 @@ def _kernel(
         for a in range(min(n_dim, q.shape[1])):
             ad = jnp.abs(q[:, a][:, None] - p[:, a][None, :])
             d2 = d2 + ad if metric == "l1" else jnp.maximum(d2, ad)
-    elif q.shape[1] <= 8:
+    elif n_dim <= 8:
         # low-d (the paper's 2D/3D domain): exact per-axis diff accumulation
         # on the VPU — the matmul identity cancels catastrophically for the
         # tiny squared distances of clustered data, and a d<=8 contraction
-        # never profits from the MXU.
+        # never profits from the MXU.  Keyed on the REAL dim: on TPU the
+        # features are lane-padded to 128, and the zero columns add nothing.
         d2 = jnp.zeros((q.shape[0], p.shape[0]), jnp.float32)
-        for a in range(q.shape[1]):
+        for a in range(n_dim):
             diff = q[:, a][:, None] - p[:, a][None, :]
             d2 = d2 + diff * diff
     else:
@@ -165,8 +173,13 @@ def pairwise_topk_padded(
     n_dim: int | None = None,  # real (pre-padding) feature dim
 ):
     """Pallas call on pre-padded operands.  See ops.pairwise_topk for the
-    user-facing wrapper (padding, defaults, CPU interpret fallback)."""
+    user-facing wrapper (padding, tile sizes, interpret mode on CPU)."""
     assert metric in ("l2", "l1", "linf"), metric
+    if not interpret and k > MAX_K:
+        raise ValueError(
+            f"pairwise_topk: k={k} exceeds the largest k the kernel compiles "
+            f"for (MAX_K={MAX_K})"
+        )
     qp, dp = queries.shape
     np_, _ = points.shape
     assert qp % tq == 0 and np_ % tp == 0
@@ -199,25 +212,25 @@ def pairwise_topk_padded(
         ],
         # VMEM-resident running buffers, persistent across the p grid axis
         scratch_shapes=_scratch_shapes(tq, k),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(queries, query_ids, points, r2)
 
 
-def _scratch_shapes(tq, k):
-    from jax.experimental.pallas import tpu as pltpu
+def query_tile(k: int) -> int:
+    """Query tile for a top-k width: the largest power of two <= 256 with
+    ``tile * k <= 8192`` (never below the 8-row sublane tile)."""
+    tq = DEFAULT_TQ
+    while tq > 8 and tq * k > _TQ_TIMES_K:
+        tq //= 2
+    return tq
 
+
+def _scratch_shapes(tq, k):
     return [
         pltpu.VMEM((tq, k), jnp.float32),
         pltpu.VMEM((tq, k), jnp.int32),
         pltpu.VMEM((tq, 1), jnp.int32),
     ]
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
-    except Exception:  # pragma: no cover
-        return None

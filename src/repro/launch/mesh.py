@@ -11,17 +11,11 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType landed after 0.4.x; explicit-Auto is the default
-# behavior there anyway, so older jax just omits the argument.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

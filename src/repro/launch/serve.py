@@ -1,14 +1,15 @@
-"""Serving launcher: batched LM serving (continuous batching) on any arch,
-or neighbor-search serving through the ``NeighborServer`` front-end.
-
-    # LM serving (continuous batching)
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
-        --requests 16 --max-new 24
+"""Serving launcher: neighbor-search serving through the ``NeighborServer``
+front-end (the default, ``--mode knn``), graph workloads, or batched LM
+serving (``--mode lm``).
 
     # neighbor search, open loop: Poisson arrivals hit the microbatching
     # server at --rate requests/second (each request = one query point)
-    PYTHONPATH=src python -m repro.launch.serve --mode knn \
+    PYTHONPATH=src python -m repro.launch.serve \
         --backend trueknn --spec hybrid --k 8 --arrival open --rate 500
+
+    # LM serving (continuous batching)
+    PYTHONPATH=src python -m repro.launch.serve --mode lm \
+        --arch qwen3-0.6b --requests 16 --max-new 24
 
     # sharded fabric end to end: a spatially-partitioned composite index
     # (N shards, radius-aware shard pruning) registered under a tenant
@@ -16,9 +17,10 @@ or neighbor-search serving through the ``NeighborServer`` front-end.
     PYTHONPATH=src python -m repro.launch.serve --mode knn \
         --backend sharded --shards 8 --index lidar --arrival open --rate 500
 
-    # device-parallel placement: pin shard blocks across 8 (forced host)
-    # devices and serve every shared-cut round as ONE fused dispatch;
-    # --devices sets XLA_FLAGS before jax loads, so this works on any CPU
+    # device-parallel placement: pin shard blocks across the devices and
+    # serve every shared-cut round as ONE fused dispatch; on a TPU host
+    # these are its chips, on CPU --devices forces N host devices (it sets
+    # XLA_FLAGS before jax loads, and is refused on any other backend)
     PYTHONPATH=src python -m repro.launch.serve --mode knn \
         --backend sharded --shards 8 --placement devices --devices 8
 
@@ -283,6 +285,12 @@ def _run_knn(args):
     if args.devices is not None:
         import jax
 
+        if jax.default_backend() != "cpu":
+            raise SystemExit(
+                f"--devices forces host-platform CPU devices; the "
+                f"{jax.default_backend()} backend ignores it (drop the flag "
+                "to use its own devices)"
+            )
         got = len(jax.devices())
         if got != args.devices:
             raise SystemExit(
@@ -439,10 +447,10 @@ def _run_workload(args):
     print(f"tenant {args.index!r} workload meter: {w}")
 
 
-def main():
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["lm", "knn", "graph", "dbscan"],
-                    default="lm")
+                    default="knn")
     # lm mode
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--requests", type=int, default=16)
@@ -461,9 +469,9 @@ def main():
                     "devices and run each shared-cut round as one fused "
                     "dispatch")
     ap.add_argument("--devices", type=int, default=None,
-                    help="force N host platform devices (sets XLA_FLAGS "
-                    "before jax loads) — lets --placement devices run on "
-                    "a plain CPU box")
+                    help="CPU only: force N host platform devices (sets "
+                    "XLA_FLAGS before jax loads) so --placement devices "
+                    "runs on a plain CPU box; refused on other backends")
     ap.add_argument("--index", default="default",
                     help="tenant name the resident index serves under")
     ap.add_argument("--max-queue", type=int, default=None,
@@ -501,7 +509,11 @@ def main():
     ap.add_argument("--explain", action="store_true",
                     help="print each tenant's active structured plan trees "
                     "(plan.explain()) once at startup")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = _parser().parse_args(argv)
     if args.devices is not None:
         # XLA reads XLA_FLAGS when the backend first initializes (first
         # jax.devices()/computation, not import), and every jax use in
@@ -515,6 +527,9 @@ def main():
             f"{flags} --xla_force_host_platform_device_count="
             f"{int(args.devices)}"
         ).strip()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "knn":
         _run_knn(args)
     elif args.mode in ("graph", "dbscan"):

@@ -33,8 +33,6 @@ def pipeline_apply(
     ``axis``); x_microbatched: (n_micro, mb, ...) replicated input; output
     (n_micro, mb, ...) — the result of all stages applied in order.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape[axis]
 
     def local(params_l, xs):  # params_l: (1, ...) slice; xs: (n_micro, mb, d)
@@ -75,10 +73,10 @@ def pipeline_apply(
         # only the last stage parked outputs; psum replicates them everywhere
         return jax.lax.psum(buf, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

@@ -46,6 +46,20 @@ def test_grid_bins_every_point_exactly_once():
     assert len(np.unique(real)) == 2000
 
 
+def test_grid_probe_agrees_with_device_binning_on_cell_boundaries():
+    """At radius 0.1 over an extent of 6.3 the cell is float32(0.1), and
+    x = 1.3 lies in cell 12 by division but in cell 13 by the float32
+    reciprocal the device multiplies with.  32 points there and 32 inside
+    cell 13: a probe that divides sizes buckets for 32, and binning would
+    drop 32 points."""
+    x = np.array([0.0, 6.3] + [1.3] * 32 + [1.35] * 32, np.float32)
+    pts = np.stack([x, np.zeros_like(x)], -1)
+    g = build_grid(pts, 0.1)
+    assert g.cap >= 64
+    b = np.asarray(g.buckets).ravel()
+    assert np.array_equal(np.sort(b[b < len(pts)]), np.arange(len(pts)))
+
+
 def test_grid_cell_size_covers_radius():
     pts = make_dataset("kitti", 1000, seed=0)
     for r in [1e-4, 0.03, 1.7, 300.0]:

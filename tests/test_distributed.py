@@ -228,7 +228,6 @@ def test_compressed_psum_shard_map_8dev():
         """
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 devs = np.array(jax.devices())
 mesh = Mesh(devs, ("data",))
@@ -243,9 +242,9 @@ def compressed_mean(x):
     return qsum.astype(jnp.float32) * scale / 8.0
 
 x = np.random.default_rng(0).normal(size=(8, 64)).astype(np.float32)
-fn = jax.jit(shard_map(compressed_mean, mesh=mesh,
-                       in_specs=P("data", None), out_specs=P(None, None),
-                       check_rep=False))
+fn = jax.jit(jax.shard_map(compressed_mean, mesh=mesh,
+                           in_specs=P("data", None), out_specs=P(None, None),
+                           check_vma=False))
 got = np.asarray(fn(x)).reshape(-1)
 want = x.mean(0)
 err = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)

@@ -223,3 +223,53 @@ def test_placed_fused_multi_round_is_one_dispatch():
     assert p.n_rounds >= 2
     assert p.timings["fused_dispatches"] == 1
     assert "/placed=1" in p.timings["plan"]
+
+
+def test_grid_over_budget_folds_table_not_cells():
+    """Past the bucket budget the hash table folds (collisions are
+    filtered exactly) instead of coarsening the cells, and a round on the
+    folded grid finds exactly what the unfolded one finds."""
+    from repro.core.fixed_radius import fixed_radius_round
+    from repro.core.grid import build_grid
+
+    pts = make_dataset("kitti", 4000, seed=2)
+    full = build_grid(pts, 0.5)
+    folded = build_grid(
+        pts, 0.5, max_bucket_elems=full.table_size * full.cap // 8
+    )
+    assert folded.res == full.res
+    assert folded.table_size < full.table_size
+    q, qid = pts[:200], np.arange(200, dtype=np.int32)
+    a = fixed_radius_round(pts, full, q, qid, 0.5, 6)
+    b = fixed_radius_round(pts, folded, q, qid, 0.5, 6)
+    assert np.array_equal(a[0], b[0]) and np.array_equal(a[2], b[2])
+
+
+def test_round_chunk_bounds_candidates_per_chunk():
+    from repro.core.fixed_radius import CHUNK_CANDIDATES, round_chunk
+
+    for d, cap in ((2, 8), (3, 32), (3, 512), (2, 1 << 16), (3, 1 << 20)):
+        c = round_chunk(2048, d, cap)
+        assert c >= 1 and c & (c - 1) == 0
+        assert c == 1 or c * 3**d * cap <= CHUNK_CANDIDATES
+
+
+def test_schedule_ends_where_a_round_cannot_beat_brute():
+    """A round that gathers more slots per query than a chunk holds and
+    than the cloud has points ends the schedule (the exact tail takes
+    over) — except under a plain stop_radius, whose partial tails need
+    the rounds."""
+    from repro.core.fixed_radius import CHUNK_CANDIDATES
+
+    idx = build_index(PTS, backend="trueknn")
+
+    class G:
+        cap = 1
+
+    g = G()
+    g.cap = CHUNK_CANDIDATES  # 9 * cap slots: past the budget and N
+    assert idx._grid_no_better_than_brute(g, None, False)
+    assert idx._grid_no_better_than_brute(g, 1.0, True)
+    assert not idx._grid_no_better_than_brute(g, 1.0, False)
+    g.cap = 64  # 576 slots >= N=500, but a chunk still holds them
+    assert not idx._grid_no_better_than_brute(g, None, False)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import make_dataset
 from repro.kernels.ops import pairwise_topk
 from repro.kernels.ref import pairwise_topk_ref
 
@@ -128,3 +129,67 @@ def test_kernel_property(nq, np_, d, k, seed, scale):
     # different summation orders; allow off-by-boundary
     diff = np.abs(np.asarray(cnt).astype(int) - np.asarray(rcnt).astype(int))
     assert diff.max() <= 2
+
+
+@pytest.mark.parametrize("d", [2, 3])
+def test_low_d_branch_keyed_on_real_dim(d):
+    """The exact per-axis form is chosen by the real feature dim, so the
+    chip's 128-lane layout computes what the CPU's 8-lane layout computes,
+    bit for bit, on clustered data where the matmul identity would cancel."""
+    import jax.numpy as jnp
+
+    from repro.kernels.pairwise_topk import pairwise_topk_padded
+
+    pts = make_dataset("porto" if d == 2 else "kitti", 1024, seed=3)
+    q = pts[:64] + np.float32(1e-4)
+
+    def run(dp, n_dim):
+        qp = jnp.zeros((64, dp), jnp.float32).at[:, :d].set(q)
+        pp = jnp.zeros((1024, dp), jnp.float32).at[:, :d].set(pts)
+        qid = jnp.full((64, 1), 1024, jnp.int32)
+        r2 = jnp.asarray([[0.01]], jnp.float32)
+        return pairwise_topk_padded(
+            qp, qid, pp, r2, k=8, n_real=1024, tq=64, tp=512,
+            interpret=True, n_dim=n_dim,
+        )
+
+    before = run(8, None)  # the CPU layout: every padded lane accumulated
+    for dp in (8, 128):
+        for got, want in zip(run(dp, d), before):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """No silent interpreter on a backend that is neither CPU nor TPU."""
+    import repro.kernels.ops as ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    q = np.zeros((8, 3), np.float32)
+    with pytest.raises(RuntimeError, match="gpu"):
+        pairwise_topk(q, q, 2)
+
+
+def test_compiled_kernel_refuses_k_above_max():
+    import jax.numpy as jnp
+
+    from repro.kernels.pairwise_topk import MAX_K, pairwise_topk_padded
+
+    with pytest.raises(ValueError, match=f"k={2 * MAX_K}"):
+        pairwise_topk_padded(
+            jnp.zeros((64, 128)), jnp.zeros((64, 1), jnp.int32),
+            jnp.zeros((512, 128)), jnp.zeros((1, 1)), k=2 * MAX_K,
+            n_real=512, tq=8, tp=512, n_dim=3,
+        )
+
+
+def test_query_tile_shrinks_as_k_grows():
+    from repro.kernels.pairwise_topk import MAX_K, query_tile
+
+    assert [query_tile(k) for k in (1, 8, 32, 64, 128, MAX_K)] == [
+        256, 256, 256, 128, 64, 32
+    ]
+    # a wide k still matches the oracle through the smaller tile
+    rng = np.random.default_rng(11)
+    q = rng.normal(size=(40, 3)).astype(np.float32)
+    p = rng.normal(size=(300, 3)).astype(np.float32)
+    _check(q, p, 64, radius=1.0)

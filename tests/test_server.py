@@ -624,3 +624,26 @@ def test_poisson_open_loop_survives_shed_requests():
     assert results == [] and lat.size == 0  # every request was shed
     assert server.stats()["rejected"] == 8
     assert not server.stats()["worker_running"]  # worker stopped cleanly
+
+
+def test_serve_launcher_defaults_to_neighbor_search():
+    from repro.launch import serve
+
+    assert serve._parser().parse_args([]).mode == "knn"
+
+
+def test_serve_launcher_refuses_devices_off_cpu(monkeypatch):
+    """--devices forces CPU host devices; on any other backend it is
+    refused, not silently ignored."""
+    import jax
+
+    from repro.launch import serve
+
+    import repro.compile_cache
+
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setattr(repro.compile_cache, "enable_compile_cache",
+                        lambda: "")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="--devices"):
+        serve.main(["--devices", "2", "--n", "64", "--batches", "1"])
