@@ -37,6 +37,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.brute import brute_knn_engine
 from repro.core.fixed_radius import CHUNK_CANDIDATES, fixed_radius_round
 from repro.core.fused_loop import build_schedule, fused_search
@@ -135,6 +136,7 @@ class TrueKNNIndex(NeighborIndex):
         self._warm_r: Optional[float] = None  # resolved-radius EMA
         self._sampled_r: Optional[float] = None  # Alg. 2 result (per cloud)
         self._probe_cache: dict = {}  # grid table-sizing probe memo
+        self._grid_build_s = 0.0  # host seconds spent in grid builds
 
         self._c = {
             "batches": 0,
@@ -164,12 +166,21 @@ class TrueKNNIndex(NeighborIndex):
                 math.log(1.001 * self._extent / r0, self._growth)
             )
 
+    def _build_grid(self, r: float, step=None):
+        """One grid build, spanned as ``trueknn.grid_build`` and timed into
+        ``_grid_build_s`` (what ``timings["grid_build_seconds"]`` reads)."""
+        t0 = time.perf_counter()
+        with trace.span("trueknn.grid_build", step=step, radius=r):
+            g = build_grid(self._pts, r, probe_cache=self._probe_cache)
+        self._grid_build_s += time.perf_counter() - t0
+        self._c["grid_builds"] += 1
+        return g
+
     def _grid_for(self, r: float):
         """Grid with cell size >= r (exactness invariant), cached on the
         radius lattice.  Returns (grid, cache_hit)."""
         if not self._cache_grids:
-            self._c["grid_builds"] += 1
-            return build_grid(self._pts, r, probe_cache=self._probe_cache), False
+            return self._build_grid(r), False
         j = min(self._lattice_j(r), self._j_cap)
         g = self._grids.pop(j, None)
         if g is not None:
@@ -181,9 +192,8 @@ class TrueKNNIndex(NeighborIndex):
         build_r = self._anchor * self._growth**j
         if j < self._j_cap:
             build_r = max(build_r, r)
-        g = build_grid(self._pts, build_r, probe_cache=self._probe_cache)
+        g = self._build_grid(build_r, j)
         self._grids[j] = g
-        self._c["grid_builds"] += 1
         while len(self._grids) > self._max_cached_grids:
             self._grids.pop(next(iter(self._grids)))
         return g, False
@@ -199,23 +209,28 @@ class TrueKNNIndex(NeighborIndex):
         if radius is not None:
             return max(float(radius), 1e-12), "explicit"
         if self._warm_start and self._warm_r is not None:
-            r = self._warm_r
-            if self._anchor is not None:
-                # snap DOWN to the lattice: conservative (at most one extra
-                # round) and guarantees grid-cache hits across batches
-                j = min(
-                    math.floor(
-                        math.log(r / self._anchor, self._growth) + 1e-9
-                    ),
-                    self._j_cap,
-                )
-                r = self._anchor * self._growth**j
-            return r, "warm"
+            j = self._warm_step()
+            if j is None:
+                return self._warm_r, "warm"
+            return self._anchor * self._growth**j, "warm"
         if shared is not None:
             return max(float(shared), 1e-12), "shared"
         if self._sampled_r is None:
             self._sampled_r = sample_start_radius(self._pts, seed=self._seed)
         return self._sampled_r, "sampled"
+
+    def _warm_step(self) -> Optional[int]:
+        """The lattice step the warm start radius snaps DOWN to — a
+        conservative start (at most one extra round) that hits the grid
+        cache across batches; None before warm state or a lattice exist."""
+        if (not self._warm_start or self._warm_r is None
+                or self._anchor is None):
+            return None
+        return min(
+            math.floor(math.log(self._warm_r / self._anchor, self._growth)
+                       + 1e-9),
+            self._j_cap,
+        )
 
     def _grid_no_better_than_brute(self, grid, stop_radius,
                                    cap_exact: bool) -> bool:
@@ -232,6 +247,13 @@ class TrueKNNIndex(NeighborIndex):
         return slots > CHUNK_CANDIDATES and slots >= self.n_points
 
     # -- the hot path ------------------------------------------------------
+
+    def _span_args(self) -> dict:
+        args = {"search": self._c["batches"]}
+        step = self._warm_step()
+        if step is not None:
+            args["start_step"] = step
+        return args
 
     def plan_details(self, spec, metric: Metric) -> tuple:
         if self._fused and isinstance(spec, (KnnSpec, HybridSpec)):
@@ -284,9 +306,9 @@ class TrueKNNIndex(NeighborIndex):
         else:
             q = np.asarray(queries, np.float32)
             qid = np.full((q.shape[0],), n, np.int32)
-        t0 = time.perf_counter()
+        b0 = self._grid_build_s
         grid, hit = self._grid_for(r)  # lattice-snapped: cell size >= r
-        t_grid = time.perf_counter() - t0
+        t_grid = self._grid_build_s - b0
         self._c["batches"] += 1
         self._c["queries_served"] += q.shape[0]
         # self-batch: the queries ARE the resident cloud, whose device
@@ -319,7 +341,7 @@ class TrueKNNIndex(NeighborIndex):
                 "plan": "native",
                 "grid_builds": 0 if hit else 1,
                 "grid_cache_hits": 1 if hit else 0,
-                "grid_build_seconds": 0.0 if hit else t_grid,
+                "grid_build_seconds": t_grid,
             },
         )
 
@@ -379,7 +401,7 @@ class TrueKNNIndex(NeighborIndex):
 
         rounds: list = []
         total_tests = 0
-        t_build = 0.0
+        b0 = self._grid_build_s
         ridx = 0
         force_brute_tail = False
         clamp_r = 4.0 * self._extent
@@ -399,7 +421,6 @@ class TrueKNNIndex(NeighborIndex):
                     break
             t0 = time.perf_counter()
             grid, hit = self._grid_for(r)
-            t_build += 0.0 if hit else time.perf_counter() - t0
             if self._grid_no_better_than_brute(grid, stop_radius, cap_exact):
                 force_brute_tail = True
                 break
@@ -526,7 +547,7 @@ class TrueKNNIndex(NeighborIndex):
             rounds=rounds,
             timings={
                 "query_seconds": time.perf_counter() - t_call,
-                "grid_build_seconds": t_build,
+                "grid_build_seconds": self._grid_build_s - b0,
                 "grid_builds": n_builds,
                 "grid_cache_hits": n_hits,
                 "start_radius_source": r_source,
@@ -574,14 +595,22 @@ class TrueKNNIndex(NeighborIndex):
         reconstruct the host driver's exact bookkeeping (rounds, warm EMA,
         counters) from the loop carry.  Returns None for schedules the
         device loop cannot improve (zero rounds) — the host loop handles
-        those verbatim."""
+        those verbatim.
+
+        The rounds run inside one program, so their ``RoundStats.seconds``
+        are 0.0: per-round device time is in a profiler trace, under the
+        named scopes ``trueknn.round.b<b>`` (``repro.trace``).
+        ``timings["grid_build_seconds"]`` counts only the grid builds the
+        schedule made (the ``trueknn.grid_build`` spans), not its cache
+        lookups."""
         n = self.n_points
         q_total = q_all.shape[0]
-        t0 = time.perf_counter()
-        sched = build_schedule(
-            self, r0, stop_radius=stop_radius, cap_exact=cap_exact
-        )
-        t_build = time.perf_counter() - t0
+        b0 = self._grid_build_s
+        with trace.span("trueknn.schedule"):
+            sched = build_schedule(
+                self, r0, stop_radius=stop_radius, cap_exact=cap_exact
+            )
+        t_build = self._grid_build_s - b0
         if not sched.radii:
             return None
         q_in = q_all
@@ -595,102 +624,107 @@ class TrueKNNIndex(NeighborIndex):
         )
         self._c["dispatches"] += 1
 
-        out_d, out_i = fr.dists, fr.idxs
-        found_all = fr.found.astype(np.int64)
-        unres = fr.unresolved  # pre-tail mask
-        rr = fr.resolved_round
-        t_final = fr.n_executed
-        n_tail = int(unres.sum())
-        tail_ran = sched.tail_mode != "none" and n_tail > 0
-        if tail_ran:
-            # the device tail replaced unresolved rows with the exact
-            # unbounded oracle answer; the hybrid re-cut and the found
-            # recount are the same host-side post-filters the host driver
-            # applies to its brute tail
-            if cap_exact:
-                from ..planner import apply_radius_cut
+        with trace.span("trueknn.finish"):
+            out_d, out_i = fr.dists, fr.idxs
+            found_all = fr.found.astype(np.int64)
+            unres = fr.unresolved  # pre-tail mask
+            rr = fr.resolved_round
+            t_final = fr.n_executed
+            n_tail = int(unres.sum())
+            tail_ran = sched.tail_mode != "none" and n_tail > 0
+            if tail_ran:
+                # the device tail replaced unresolved rows with the exact
+                # unbounded oracle answer; the hybrid re-cut and the found
+                # recount are the same host-side post-filters the host driver
+                # applies to its brute tail
+                if cap_exact:
+                    from ..planner import apply_radius_cut
 
-                bd, bi, bfound = apply_radius_cut(
-                    out_d[unres], out_i[unres], stop_radius, n
+                    bd, bi, bfound = apply_radius_cut(
+                        out_d[unres], out_i[unres], stop_radius, n
+                    )
+                    out_d[unres] = bd
+                    out_i[unres] = bi
+                    found_all[unres] = bfound
+                else:
+                    found_all[unres] = np.isfinite(out_d[unres]).sum(1)
+                self._c["brute_tail_queries"] += n_tail
+
+            radii = np.asarray(sched.radii, np.float64)
+            alive_forever = rr < 0
+            rounds = []
+            total_tests = 0
+            for t in range(t_final):
+                m = int(np.sum(alive_forever | (rr >= t)))
+                n_res = int(np.sum(rr == t))
+                tests_t = int(fr.tests[t])
+                g = sched.grids[t]
+                rounds.append(
+                    RoundStats(t, float(radii[t]), m, n_res, tests_t,
+                               g.res, g.cap, 0.0,
+                               cache_hit=sched.cache_hits[t])
                 )
-                out_d[unres] = bd
-                out_i[unres] = bi
-                found_all[unres] = bfound
-            else:
-                found_all[unres] = np.isfinite(out_d[unres]).sum(1)
-            self._c["brute_tail_queries"] += n_tail
+                total_tests += tests_t
+            if tail_ran:
+                btests = n_tail * n
+                rounds.append(
+                    RoundStats(t_final, float("inf"), n_tail, n_tail, btests,
+                               (), 0, 0.0)
+                )
+                total_tests += btests
 
-        radii = np.asarray(sched.radii, np.float64)
-        alive_forever = rr < 0
-        rounds = []
-        total_tests = 0
-        for t in range(t_final):
-            m = int(np.sum(alive_forever | (rr >= t)))
-            n_res = int(np.sum(rr == t))
-            tests_t = int(fr.tests[t])
-            g = sched.grids[t]
-            rounds.append(
-                RoundStats(t, float(radii[t]), m, n_res, tests_t,
-                           g.res, g.cap, 0.0,
-                           cache_hit=sched.cache_hits[t])
+            resolved_at = np.where(
+                rr >= 0, radii[np.clip(rr, 0, len(radii) - 1)], np.nan
             )
-            total_tests += tests_t
-        if tail_ran:
-            btests = n_tail * n
-            rounds.append(
-                RoundStats(t_final, float("inf"), n_tail, n_tail, btests,
-                           (), 0, 0.0)
+            p50 = self._update_warm(resolved_at)
+
+            n_builds = sum(
+                1 for rs in rounds
+                if np.isfinite(rs.radius) and not rs.cache_hit
             )
-            total_tests += btests
+            n_hits = sum(1 for rs in rounds if rs.cache_hit)
+            self._c["batches"] += 1
+            self._c["queries_served"] += q_total
+            self._c["rounds"] += len(rounds)
 
-        resolved_at = np.where(
-            rr >= 0, radii[np.clip(rr, 0, len(radii) - 1)], np.nan
-        )
-        p50 = self._update_warm(resolved_at)
+            if ctx is not None and getattr(ctx, "canonical_shapes", False):
+                ctx.record_bucket(
+                    ("fused", "hybrid" if cap_exact else "knn", k, fr.q_pad,
+                     sched.signature())
+                )
 
-        n_builds = sum(
-            1 for rs in rounds
-            if np.isfinite(rs.radius) and not rs.cache_hit
-        )
-        n_hits = sum(1 for rs in rounds if rs.cache_hit)
-        self._c["batches"] += 1
-        self._c["queries_served"] += q_total
-        self._c["rounds"] += len(rounds)
-
-        if ctx is not None and getattr(ctx, "canonical_shapes", False):
-            ctx.record_bucket(
-                ("fused", "hybrid" if cap_exact else "knn", k, fr.q_pad,
-                 sched.signature())
+            return KNNResult(
+                dists=out_d,
+                idxs=out_i,
+                n_tests=total_tests,
+                backend=self.backend_name,
+                metric=metric_name,
+                found=found_all,
+                rounds=rounds,
+                timings={
+                    "query_seconds": time.perf_counter() - t_call,
+                    "grid_build_seconds": t_build,
+                    "grid_builds": n_builds,
+                    "grid_cache_hits": n_hits,
+                    "start_radius_source": r_source,
+                    "warm_start_radius": r0 if r_source == "warm" else None,
+                    "plan": f"fused/rounds<={len(sched.radii)}",
+                    "fused_dispatches": 1,
+                    "resolved_radius_p50": p50,
+                },
+                start_radius=r0,
+                final_radius=rounds[-1].radius if rounds else r0,
             )
-
-        return KNNResult(
-            dists=out_d,
-            idxs=out_i,
-            n_tests=total_tests,
-            backend=self.backend_name,
-            metric=metric_name,
-            found=found_all,
-            rounds=rounds,
-            timings={
-                "query_seconds": time.perf_counter() - t_call,
-                "grid_build_seconds": t_build,
-                "grid_builds": n_builds,
-                "grid_cache_hits": n_hits,
-                "start_radius_source": r_source,
-                "warm_start_radius": r0 if r_source == "warm" else None,
-                "plan": f"fused/rounds<={len(sched.radii)}",
-                "fused_dispatches": 1,
-                "resolved_radius_p50": p50,
-            },
-            start_radius=r0,
-            final_radius=rounds[-1].radius if rounds else r0,
-        )
 
     def stats(self) -> dict:
         s = super().stats()
         s.update(self._c)
         s["cached_grids"] = len(self._grids)
         s["warm_radius"] = self._warm_r
+        # the radius lattice: anchor * growth**step, and the step the next
+        # warm start begins at (None until the first search sets them)
+        s["lattice_anchor"] = self._anchor
+        s["warm_lattice_step"] = self._warm_step()
         s["fused"] = self._fused
         s["grid_probe_hits"] = int(self._probe_cache.get("_hits", 0))
         s["grid_probe_misses"] = int(self._probe_cache.get("_misses", 0))

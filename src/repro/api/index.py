@@ -30,6 +30,7 @@ from typing import Optional, Union
 
 import numpy as np
 
+from repro import trace
 from repro.core.result import KNNResult, RangeResult
 
 from .metrics import Metric, get_metric
@@ -108,6 +109,11 @@ class NeighborIndex(abc.ABC):
 
     def __len__(self) -> int:
         return self.n_points
+
+    def _span_args(self) -> dict:
+        """What the ``index.query`` span records of this index's state
+        (``repro.trace``); backends extend this."""
+        return {}
 
     def stats(self) -> dict:
         """Cumulative counters since build; backends extend this."""
@@ -201,7 +207,13 @@ class NeighborIndex(abc.ABC):
         # shapes (no canonicalization), so one-shot callers see exactly the
         # engine shapes and counters they always did.  Hold a prepared plan
         # (``index.prepare``) to amortize planning and compiled executables.
-        return QueryPlan(self, spec, metric, canonical_shapes=False)(queries)
+        args = {}
+        if trace.enabled():
+            rows = self.n_points if queries is None else len(queries)
+            args = dict(self._span_args(), rows=rows)
+        with trace.span("index.query", **args):
+            return QueryPlan(self, spec, metric, canonical_shapes=False)(
+                queries)
 
     def prepare(
         self,

@@ -87,6 +87,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import trace
 from repro.core.grid import _next_pow2
 from repro.core.partition import morton_codes
 from repro.core.result import KNNResult, RangeResult
@@ -793,7 +794,8 @@ class NeighborServer:
                 return self._run_writes(name, batch)
             if isinstance(spec, _WorkloadSpec):
                 return self._run_workloads(name, batch)
-            return self._run_batch(name, spec, metric, batch)
+            with trace.span("server.batch", rows=len(batch)):
+                return self._run_batch(name, spec, metric, batch)
         finally:
             with self._lock:
                 left = self._inflight.get(name, 0) - len(batch)
@@ -1200,7 +1202,8 @@ class NeighborServer:
         t0 = time.perf_counter()
         try:
             with self._serve_lock:  # one plan execution in flight at a time
-                res = plan(rows)
+                with trace.span("server.execute"):
+                    res = plan(rows)
         except BaseException as e:
             # fail every ticket in the batch rather than stranding waiters
             with self._lock:

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
+
 from .brute import _brute_impl
 from .fixed_radius import _chunk_candidates, _pad_points, round_chunk
 from .grid import _next_pow2, stencil_offsets
@@ -193,10 +195,16 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
     tail form and the chunk geometry.  Everything else — the grids' bucket
     arrays, the per-round squared radii, the query batch — is traced, so
     warm batches whose schedules share a shape reuse the executable.
+
+    The jitted function is called ``run`` (its program is ``jit_run``), and
+    its ops carry the named scopes ``trueknn.fused`` (all of it),
+    ``trueknn.round.b<b>`` (grid branch ``b``) and ``trueknn.tail``: a
+    device trace reads per-round and tail time from them.
     """
     n_sched = len(branch_of)
     branch_lookup = jnp.asarray(np.asarray(branch_of, np.int32))
 
+    @jax.named_scope("trueknn.fused")
     def run(pts_padded, grids, q, qid, r2s):
         n = pts_padded.shape[0] - 1
         d = pts_padded.shape[1]
@@ -208,6 +216,7 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
             table_size = branch_tables[b]
             cb = round_chunk(chunk, d, buckets.shape[1])
 
+            @jax.named_scope(f"trueknn.round.b{b}")
             def branch(carry):
                 best_d2, best_i, found, unres, res_round, tests_vec, t = carry
                 r2 = r2s[t]
@@ -288,9 +297,10 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
                 bi_ = bi_.at[rows].set(jnp.where(live[:, None], it, bi_[rows]))
                 return bd_, bi_
 
-            best_d, best_i = _over_rows(
-                unres, tail_chunk, tail_chunk_fn, (best_d, best_i)
-            )
+            with jax.named_scope("trueknn.tail"):
+                best_d, best_i = _over_rows(
+                    unres, tail_chunk, tail_chunk_fn, (best_d, best_i)
+                )
         return best_d, best_i, found, unres, res_round, tests_vec, t
 
     return jax.jit(run)
@@ -305,56 +315,61 @@ def fused_search(points, schedule: FusedSchedule, queries, query_ids,
     N otherwise).  The batch is padded once to a power of two; the only
     host sync is the final result fetch.
     """
-    q = jnp.asarray(queries, jnp.float32)
-    qid = jnp.asarray(query_ids, jnp.int32)
-    q_total = q.shape[0]
-    q_pad = _next_pow2(max(q_total, 1))
-    chunk = _floor_pow2(min(int(chunk), q_pad))
-    if q_pad > q_total:
-        q = jnp.concatenate(
-            [q, jnp.full((q_pad - q_total, q.shape[1]), jnp.inf, q.dtype)]
-        )
-        qid = jnp.concatenate(
-            [qid, jnp.full((q_pad - q_total,), schedule.grids[0].n_points,
-                           qid.dtype)]
-        )
-    pts = _pad_points(jnp.asarray(points, jnp.float32))
+    with trace.span("trueknn.dispatch"):
+        q = jnp.asarray(queries, jnp.float32)
+        qid = jnp.asarray(query_ids, jnp.int32)
+        q_total = q.shape[0]
+        q_pad = _next_pow2(max(q_total, 1))
+        chunk = _floor_pow2(min(int(chunk), q_pad))
+        if q_pad > q_total:
+            q = jnp.concatenate(
+                [q, jnp.full((q_pad - q_total, q.shape[1]), jnp.inf,
+                             q.dtype)]
+            )
+            qid = jnp.concatenate(
+                [qid, jnp.full((q_pad - q_total,),
+                               schedule.grids[0].n_points, qid.dtype)]
+            )
+        pts = _pad_points(jnp.asarray(points, jnp.float32))
 
-    # dedupe repeated grids (post-lattice-cap rounds share the single-cell
-    # grid) into switch branches; the round->branch map is static
-    seen: dict = {}
-    branch_of = []
-    branch_grids = []
-    for g in schedule.grids:
-        b = seen.get(id(g))
-        if b is None:
-            b = len(branch_grids)
-            seen[id(g)] = b
-            branch_grids.append(g)
-        branch_of.append(b)
-    grid_args = tuple(
-        (g.buckets, g.point_cells, g.origin, g.inv_cell, g.res_arr)
-        for g in branch_grids
-    )
-    # host numpy f32 square == device f32 square (same IEEE multiply)
-    r2s = jnp.asarray(np.asarray(schedule.radii, np.float32) ** 2)
+        # dedupe repeated grids (post-lattice-cap rounds share the
+        # single-cell grid) into switch branches; the round->branch map is
+        # static
+        seen: dict = {}
+        branch_of = []
+        branch_grids = []
+        for g in schedule.grids:
+            b = seen.get(id(g))
+            if b is None:
+                b = len(branch_grids)
+                seen[id(g)] = b
+                branch_grids.append(g)
+            branch_of.append(b)
+        grid_args = tuple(
+            (g.buckets, g.point_cells, g.origin, g.inv_cell, g.res_arr)
+            for g in branch_grids
+        )
+        # host numpy f32 square == device f32 square (same IEEE multiply)
+        r2s = jnp.asarray(np.asarray(schedule.radii, np.float32) ** 2)
 
-    fn = _fused_fn(
-        tuple(g.table_size for g in branch_grids),
-        tuple(branch_of),
-        schedule.tail_mode != "none",
-        int(k),
-        chunk,
-        min(512, q_pad),
-    )
-    bd, bi, found, unres, res_round, tests, t = fn(pts, grid_args, q, qid, r2s)
-    return FusedResult(
-        dists=np.array(bd[:q_total]),
-        idxs=np.array(bi[:q_total]),
-        found=np.array(found[:q_total]),
-        unresolved=np.array(unres[:q_total]),
-        resolved_round=np.array(res_round[:q_total]),
-        tests=np.asarray(tests, np.float64),
-        n_executed=int(t),
-        q_pad=q_pad,
-    )
+        fn = _fused_fn(
+            tuple(g.table_size for g in branch_grids),
+            tuple(branch_of),
+            schedule.tail_mode != "none",
+            int(k),
+            chunk,
+            min(512, q_pad),
+        )
+        bd, bi, found, unres, res_round, tests, t = fn(
+            pts, grid_args, q, qid, r2s)
+    with trace.span("trueknn.fetch"):
+        return FusedResult(
+            dists=np.array(bd[:q_total]),
+            idxs=np.array(bi[:q_total]),
+            found=np.array(found[:q_total]),
+            unresolved=np.array(unres[:q_total]),
+            resolved_round=np.array(res_round[:q_total]),
+            tests=np.asarray(tests, np.float64),
+            n_executed=int(t),
+            q_pad=q_pad,
+        )

@@ -58,7 +58,10 @@ class RoundStats:
     ``stop_radius`` early-break, the extent clamp and the brute-force tail
     (``radius == inf``, ``grid_res == ()``) all report truthfully.
     ``cache_hit`` marks rounds that reused a cached grid instead of
-    rebuilding (see the ``trueknn`` backend's grid cache).
+    rebuilding (see the ``trueknn`` backend's grid cache).  ``seconds`` is
+    the host loop's wall time for the round; the fused driver runs every
+    round in one program and reports 0.0 (a profiler trace holds each
+    round's device time under the named scope ``trueknn.round.b<b>``).
     """
 
     round_idx: int

@@ -169,6 +169,86 @@ def test_fused_resolved_radius_p50_reported():
     assert h.timings["resolved_radius_p50"] > 0
 
 
+# ------------------------------------------------ spans and named scopes
+
+
+def test_fused_answers_identical_with_spans_on():
+    """Spans are host annotations only: the same searches with spans on
+    and off give bit-identical answers and schedules."""
+    from repro import trace
+
+    off = build_index(PTS, backend="trueknn")
+    on = build_index(PTS, backend="trueknn")
+    want = [off.query(QS, KnnSpec(5)) for _ in range(2)]
+    trace.enable(True)
+    try:
+        got = [on.query(QS, KnnSpec(5)) for _ in range(2)]
+    finally:
+        trace.enable(False)
+    for a, b in zip(want, got):
+        _same(a, b)
+        assert [r.radius for r in a.rounds] == [r.radius for r in b.rounds]
+
+
+def test_fused_program_is_jit_run_with_named_scopes(monkeypatch):
+    """The device trace finds the fused program by its jit name and its
+    rounds and tail by named scope."""
+    from repro.core import fused_loop
+
+    calls = []
+    real = fused_loop._fused_fn
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            calls.append((fn, args))
+            return fn(*args)
+
+        return call
+
+    monkeypatch.setattr(fused_loop, "_fused_fn", spy)
+    res = build_index(PTS, backend="trueknn").query(
+        QS, KnnSpec(5, start_radius=1e-3))
+    assert res.n_rounds >= 2
+    ((fn, args),) = calls
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert "module @jit_run " in text
+    for scope in ("trueknn.fused", "trueknn.round.b0", "trueknn.round.b1",
+                  "trueknn.tail"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_grid_build_seconds_count_grid_builds_only():
+    """A fused search reports the time of the grids it built, not of its
+    whole schedule; a search that builds none reports 0.0.  Its rounds'
+    seconds are 0.0 (their device time is in the trace)."""
+    index = build_index(PTS, backend="trueknn")
+    cold = index.query(QS, KnnSpec(5))
+    assert cold.timings["grid_build_seconds"] > 0.0
+    index.query(QS, KnnSpec(5))
+    before = index.stats()["grid_builds"]
+    warm = index.query(QS, KnnSpec(5))
+    assert index.stats()["grid_builds"] == before
+    assert warm.timings["grid_build_seconds"] == 0.0
+    assert all(r.seconds == 0.0 for r in warm.rounds)
+
+
+def test_lattice_step_is_read_from_stats_and_the_span():
+    index = build_index(PTS, backend="trueknn")
+    s = index.stats()
+    assert s["lattice_anchor"] is None and s["warm_lattice_step"] is None
+    assert index._span_args() == {"search": 0}
+    index.query(QS, KnnSpec(5))
+    s = index.stats()
+    step = s["warm_lattice_step"]
+    assert isinstance(step, int)
+    assert index._span_args() == {"search": 1, "start_step": step}
+    res = index.query(QS, KnnSpec(5))
+    assert res.timings["start_radius_source"] == "warm"
+    assert res.start_radius == s["lattice_anchor"] * 2.0**step
+
+
 def test_grid_probe_cache_memoizes_table_sizing():
     """The table-sizing probe memoizes per (point cloud, initial res): a
     rebuild at a probed resolution skips the O(N) host probe, and the
