@@ -18,7 +18,6 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fixed_radius import fixed_radius_round
@@ -52,7 +51,6 @@ class FixedRadiusIndex(NeighborIndex):
         self._default_radius = radius
         self._chunk = int(chunk)
         self._max_cached_grids = max(1, int(max_cached_grids))
-        self._pts_j = jnp.asarray(self._pts)
         self._grids: dict = {}  # radius -> Grid (insertion-ordered LRU)
         self._grid_builds = 0
         self._grid_cache_hits = 0
@@ -84,7 +82,7 @@ class FixedRadiusIndex(NeighborIndex):
         grid, hit = self._grid_for(r)
         t_grid = time.perf_counter() - t0
         d2, idx, found, n_tests = fixed_radius_round(
-            self._pts_j, grid, q, qid, r, k, chunk=self._chunk
+            grid, q, qid, r, k, chunk=self._chunk
         )
         dt = time.perf_counter() - t0
         found = np.asarray(found)
@@ -148,8 +146,7 @@ class FixedRadiusIndex(NeighborIndex):
 
         def round_fn(k):
             d2, idx, found, n_tests = fixed_radius_round(
-                self._pts_j, grid, q, qid, float(spec.radius), int(k),
-                chunk=self._chunk,
+                grid, q, qid, float(spec.radius), int(k), chunk=self._chunk,
             )
             return (
                 np.sqrt(np.asarray(d2)),

@@ -39,7 +39,11 @@ import numpy as np
 
 from repro import trace
 from repro.core.brute import brute_knn_engine
-from repro.core.fixed_radius import CHUNK_CANDIDATES, fixed_radius_round
+from repro.core.fixed_radius import (
+    CHUNK_CANDIDATES,
+    fixed_radius_round,
+    round_slots,
+)
 from repro.core.fused_loop import build_schedule, fused_search
 from repro.core.grid import _next_pow2, build_grid
 from repro.core.result import KNNResult, RoundStats
@@ -150,6 +154,10 @@ class TrueKNNIndex(NeighborIndex):
             # query block instead of re-uploading the host array (counted
             # per dispatch that took the aliased path)
             "query_upload_skips": 0,
+            # grid rounds: candidate slots gathered, and the distance
+            # evaluations among them (slot fill = round_tests / round_slots)
+            "round_slots": 0,
+            "round_tests": 0,
         }
 
     # -- radius lattice & grid cache --------------------------------------
@@ -248,6 +256,15 @@ class TrueKNNIndex(NeighborIndex):
 
     # -- the hot path ------------------------------------------------------
 
+    def _count_round(self, rows: int, grid, tests: int) -> int:
+        """Count one ``fixed_radius_round`` over ``rows`` query rows into
+        the slot counters; returns the slots it gathered."""
+        slots = round_slots(rows, min(self._chunk, max(1, rows)), self.dim,
+                            grid.cap)
+        self._c["round_slots"] += slots
+        self._c["round_tests"] += int(tests)
+        return slots
+
     def _span_args(self) -> dict:
         args = {"search": self._c["batches"]}
         step = self._warm_step()
@@ -320,8 +337,9 @@ class TrueKNNIndex(NeighborIndex):
             if q_dev is self._pts_j:
                 self._c["query_upload_skips"] += 1
             d2, idx, found, n_tests = fixed_radius_round(
-                self._pts_j, grid, q_dev, qid, r, int(k), chunk=self._chunk
+                grid, q_dev, qid, r, int(k), chunk=self._chunk
             )
+            self._count_round(q.shape[0], grid, n_tests)
             self._c["rounds"] += 1
             self._c["dispatches"] += 1
             return (
@@ -433,9 +451,9 @@ class TrueKNNIndex(NeighborIndex):
                 # the valid mask excludes from answers and n_tests alike)
                 self._c["query_upload_skips"] += 1
                 d2, idx, found, tests = fixed_radius_round(
-                    self._pts_j, grid, self._pts_j, qid_all, r, k,
-                    chunk=self._chunk,
+                    grid, self._pts_j, qid_all, r, k, chunk=self._chunk,
                 )
+                slots = self._count_round(q_total, grid, tests)
             else:
                 m_pad = _next_pow2(m)
                 q = np.full((m_pad, d), np.inf, dtype=np.float32)
@@ -443,9 +461,9 @@ class TrueKNNIndex(NeighborIndex):
                 qid = np.full((m_pad,), n, dtype=np.int32)
                 qid[:m] = qid_all[alive]
                 d2, idx, found, tests = fixed_radius_round(
-                    self._pts_j, grid, q, qid, r, k,
-                    chunk=min(self._chunk, m_pad),
+                    grid, q, qid, r, k, chunk=min(self._chunk, m_pad),
                 )
+                slots = self._count_round(m_pad, grid, tests)
             self._c["dispatches"] += 1
             d2 = np.asarray(d2[:m])
             idx = np.asarray(idx[:m])
@@ -470,7 +488,8 @@ class TrueKNNIndex(NeighborIndex):
             dt = time.perf_counter() - t0
             rounds.append(
                 RoundStats(ridx, r, m, int(resolved.sum()), int(tests),
-                           grid.res, grid.cap, dt, cache_hit=hit)
+                           grid.res, grid.cap, dt, cache_hit=hit,
+                           n_slots=slots)
             )
             ridx += 1
 
@@ -658,13 +677,17 @@ class TrueKNNIndex(NeighborIndex):
                 m = int(np.sum(alive_forever | (rr >= t)))
                 n_res = int(np.sum(rr == t))
                 tests_t = int(fr.tests[t])
+                slots_t = int(fr.slots[t])
                 g = sched.grids[t]
                 rounds.append(
                     RoundStats(t, float(radii[t]), m, n_res, tests_t,
                                g.res, g.cap, 0.0,
-                               cache_hit=sched.cache_hits[t])
+                               cache_hit=sched.cache_hits[t],
+                               n_slots=slots_t)
                 )
                 total_tests += tests_t
+                self._c["round_tests"] += tests_t
+                self._c["round_slots"] += slots_t
             if tail_ran:
                 btests = n_tail * n
                 rounds.append(

@@ -77,44 +77,48 @@ def build_stacked_grids(pts_shards: np.ndarray, n_valid: np.ndarray, radius: flo
     stack = lambda xs: jnp.stack(xs)
     return {
         "buckets": stack([g.buckets for g in grids]),
-        "point_cells": stack([g.point_cells for g in grids]),
+        "planes": tuple(
+            stack([g.planes[a] for g in grids]) for a in range(d)
+        ),
         "origin": stack([g.origin for g in grids]),
         "inv_cell": stack([g.inv_cell for g in grids]),
         "res": stack([g.res_arr for g in grids]),
     }, table_size, cap
 
 
-def make_grid_round(mesh: Mesh, k: int, table_size: int, *, chunk: int = 1024,
-                    point_axis: str = "model"):
-    """shard_map'd fixed-radius round over stacked per-shard grids.
+def make_grid_round(mesh: Mesh, k: int, table_size: int, n_local: int, *,
+                    chunk: int = 1024, point_axis: str = "model"):
+    """shard_map'd fixed-radius round over stacked per-shard grids, each
+    built over ``n_local`` point rows (the shard's sentinel id).
 
-    fn(pts (P,Nl+1,d) w/ sentinel row, grids dict, queries (Q,d),
-       query_ids (Q,), r2 ()) ->
+    fn(buckets (P,H,cap), planes d x (P,H,cap), origin (P,d),
+       inv_cell (P,d), res (P,d), queries (Q,d), query_ids (Q,), r2 ()) ->
        (d2 (Q,k), idx (Q,k) global, found (Q,), tests ())
     """
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     p_size = mesh.shape[point_axis]
     assert p_size & (p_size - 1) == 0
 
-    def local_fn(pts_l, buckets, cells, origin, inv_cell, res, q_l, qid_l, r2):
+    def local_fn(buckets, planes, origin, inv_cell, res, q_l, qid_l, r2):
         # strip the size-1 shard dim shard_map leaves on sharded operands
-        pts_l, buckets, cells = pts_l[0], buckets[0], cells[0]
+        buckets, planes = buckets[0], tuple(p[0] for p in planes)
         origin, inv_cell, res = origin[0], inv_cell[0], res[0]
-        nl = pts_l.shape[0] - 1  # sentinel row appended upstream
-        n_global = nl * p_size
+        n_global = n_local * p_size
         shard = jax.lax.axis_index(point_axis)
         qid_local = jnp.where(
-            (qid_l >= shard * nl) & (qid_l < (shard + 1) * nl),
-            qid_l - shard * nl,
-            nl,
+            (qid_l >= shard * n_local) & (qid_l < (shard + 1) * n_local),
+            qid_l - shard * n_local,
+            n_local,
         ).astype(jnp.int32)
         q_chunk = min(chunk, q_l.shape[0])
         d2, idx, found, tests = _round_impl(
-            pts_l, buckets, cells, origin, inv_cell, res,
+            buckets, planes, origin, inv_cell, res,
             q_l, qid_local, r2,
-            table_size=table_size, k=k, chunk=q_chunk,
+            n=n_local, table_size=table_size, k=k, chunk=q_chunk,
         )
-        idx = jnp.where(idx < nl, idx + shard * nl, n_global).astype(jnp.int32)
+        idx = jnp.where(
+            idx < n_local, idx + shard * n_local, n_global
+        ).astype(jnp.int32)
 
         # hypercube merge of in-radius partial top-k + found counts
         step = 1
@@ -140,7 +144,7 @@ def make_grid_round(mesh: Mesh, k: int, table_size: int, *, chunk: int = 1024,
     return jax.shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=(gspec, gspec, gspec, gspec, gspec, gspec,
+        in_specs=(gspec, gspec, gspec, gspec, gspec,
                   qspec, P(batch_axes or None), P()),
         out_specs=(qspec, qspec, P(batch_axes or None), P()),
         check_vma=False,
@@ -167,10 +171,6 @@ def distributed_trueknn_grid(
     p_size = mesh.shape[point_axis]
     shards, n_valid = shard_points(pts, p_size)
     nl = shards.shape[1]
-    # sentinel +inf row per shard (gathers of bucket-pad index nl land here)
-    shards_pad = np.concatenate(
-        [shards, np.full((p_size, 1, d), np.inf, np.float32)], axis=1
-    )
 
     if queries is None:
         q_all = pts
@@ -191,14 +191,14 @@ def distributed_trueknn_grid(
     qsh = NamedSharding(mesh, P(batch_axes or None, None))
     idsh = NamedSharding(mesh, P(batch_axes or None))
     gsh = NamedSharding(mesh, P(point_axis))
-    pts_j = jax.device_put(shards_pad, gsh)
 
     stats = {"rounds": [], "total_tests": 0, "start_radius": r0}
     rounds = 0
     while alive.size and rounds < max_rounds:
         grids, table_size, cap = build_stacked_grids(shards, n_valid, r)
         grids = {kk: jax.device_put(v, gsh) for kk, v in grids.items()}
-        fn = jax.jit(make_grid_round(mesh, k, table_size, point_axis=point_axis))
+        fn = jax.jit(make_grid_round(mesh, k, table_size, nl,
+                                     point_axis=point_axis))
 
         m = alive.size
         m_pad = max(bsz, 1 << max(0, (m - 1).bit_length()))
@@ -207,7 +207,7 @@ def distributed_trueknn_grid(
         qid = np.full((m_pad,), -1, np.int32)
         qid[:m] = qid_all[alive]
         d2, idx, found, tests = fn(
-            pts_j, grids["buckets"], grids["point_cells"], grids["origin"],
+            grids["buckets"], grids["planes"], grids["origin"],
             grids["inv_cell"], grids["res"],
             jax.device_put(q, qsh), jax.device_put(qid, idsh),
             jnp.float32(r) ** 2,

@@ -25,7 +25,7 @@ import numpy as np
 from .grid import Grid, stencil_offsets
 
 __all__ = ["fixed_radius_knn", "fixed_radius_round", "round_chunk",
-           "CHUNK_CANDIDATES"]
+           "round_slots", "CHUNK_CANDIDATES"]
 
 # Candidate slots (queries x 3^d x cap) one query chunk gathers at once.
 # The round's temporaries grow with it, and so does the TPU compiler's time
@@ -41,16 +41,17 @@ def round_chunk(chunk: int, d: int, cap: int) -> int:
     return int(min(chunk, 1 << (fit.bit_length() - 1)))
 
 
-def _pad_points(points: jax.Array) -> jax.Array:
-    """Append a sentinel +inf row so bucket-pad gathers resolve harmlessly."""
-    sentinel = jnp.full((1, points.shape[1]), jnp.inf, points.dtype)
-    return jnp.concatenate([points, sentinel], axis=0)
+def round_slots(rows: int, chunk: int, d: int, cap: int) -> int:
+    """Candidate slots a grid round gathers over ``rows`` query rows taken
+    ``chunk`` at a time: each chunk is cut by ``round_chunk`` and padded
+    full, and each of its rows gathers 3^d buckets of ``cap`` slots."""
+    cb = round_chunk(chunk, d, cap)
+    return -(-int(rows) // cb) * cb * 3**d * cap
 
 
 def _chunk_candidates(
-    points_padded,  # (N+1, d) with +inf sentinel row
-    buckets,  # (H, cap)
-    point_cells,  # (N+1, d) int32 cell coords, sentinel row -2
+    buckets,  # (H, cap) int32 point ids, sentinel n
+    planes,  # d arrays (H, cap) float32: the same slots' coordinates
     origin,
     inv_cell,
     res_arr,  # (d,) int32, dynamic virtual resolution
@@ -59,15 +60,20 @@ def _chunk_candidates(
     qid,  # (chunk,) int32
     r2,  # scalar squared radius
     *,
+    n: int,
     table_size: int,
     k: int,
 ):
     """One chunk of grid-stencil candidate search: gather the one-ring
-    stencil's bucket contents, score squared distances, keep the k best
-    within ``r2``.  Shared by the per-round host driver (``_round_impl``)
-    and the fused multi-round loop (``repro.core.fused_loop``) so both
-    trace the *same* ops — bit-identity between them holds by
-    construction, not by tolerance.
+    stencil's bucket rows (ids and coordinate planes), score squared
+    distances, keep the k best within ``r2``.  ``n`` is the sentinel id
+    (the number of points the grid was built over).  Shared by the
+    per-round host loop (``_round_impl``) and the fused multi-round loop
+    (``repro.core.fused_loop``) so both trace the *same* ops — bit-identity
+    between them holds by construction, not by tolerance.
+
+    Every word a candidate needs arrives in a row gather of ``cap``
+    contiguous slots; no point is gathered by id.
 
     Returns ``(top_d2 (chunk, k), top_i (chunk, k), found (chunk,),
     valid (chunk, n_cand))`` — ``valid`` is the per-candidate
@@ -75,27 +81,39 @@ def _chunk_candidates(
     """
     from .grid import cell_coords_of, hash_coords
 
-    n = points_padded.shape[0] - 1
+    d = len(planes)
     cap = buckets.shape[1]
     chunk = q.shape[0]
     n_cand = offs.shape[0] * cap
 
     qfin = jnp.where(jnp.isfinite(q), q, 0.0)  # keep pad-query math finite
-    coords = cell_coords_of(qfin, origin, inv_cell, res_arr)
-    nbr = coords[:, None, :] + offs[None, :, :]  # (chunk, S, d)
-    in_range = jnp.all((nbr >= 0) & (nbr < res_arr), axis=-1)  # (chunk, S)
-    h = hash_coords(nbr, table_size)  # (chunk, S)
-    # candidate point indices, (chunk, S*cap); out-of-range cells -> N
-    cand = jnp.where(in_range[..., None], buckets[h], n)
+    coords = cell_coords_of(qfin, origin, inv_cell, res_arr)  # (chunk, d)
+    nbr = coords[None, :, :] + offs[:, None, :]  # (S, chunk, d)
+    h = hash_coords(nbr, table_size)  # (S, chunk)
+    # one row gather per array, stencil cell outermost: candidate ids and
+    # each axis's coordinates, (S, chunk, cap)
+    cand = buckets[h]
+    xs = [planes[a][h] for a in range(d)]
     # exact cell-coord match kills hash collisions (and duplicates): the
-    # integer compare is our ray-AABB test analogue.
-    ccell = point_cells[cand]  # (chunk, S, cap, d)
-    match = jnp.all(ccell == nbr[:, :, None, :], axis=-1)
-    cand = jnp.where(match, cand, n).reshape(chunk, n_cand)
-    cpts = points_padded[cand]  # (chunk, n_cand, d)
-    diff = cpts - q[:, None, :]
+    # integer compare is our ray-AABB test analogue.  Each candidate's cell
+    # is recomputed from its coordinates as ``grid._bucket_order`` binned
+    # it.  Candidate cells are clipped into the grid, so a stencil cell
+    # outside it matches nothing.
+    for a, x in enumerate(xs):
+        cell = cell_coords_of(
+            jnp.where(jnp.isfinite(x), x, 0.0), origin[a], inv_cell[a],
+            res_arr[a],
+        )
+        cand = jnp.where(cell == nbr[:, :, a:a + 1], cand, n)
+    diff = jnp.stack(xs, axis=-1) - q[None, :, None, :]
     d2 = jnp.sum(diff * diff, axis=-1)
     d2 = jnp.nan_to_num(d2, nan=jnp.inf, posinf=jnp.inf)
+
+    def per_query(v):  # (S, chunk, cap) -> (chunk, S * cap), stencil-major
+        return jnp.moveaxis(v, 0, 1).reshape(chunk, n_cand)
+
+    cand = per_query(cand)
+    d2 = per_query(d2)
     valid = (cand < n) & jnp.isfinite(q[:, :1])  # pad queries don't count
     not_self = cand != qid[:, None]
     within = valid & not_self & (d2 <= r2)
@@ -112,23 +130,23 @@ def _chunk_candidates(
     return top_d, top_i, found, valid
 
 
-@partial(jax.jit, static_argnames=("table_size", "k", "chunk"))
+@partial(jax.jit, static_argnames=("n", "table_size", "k", "chunk"))
 def _round_impl(
-    points_padded,  # (N+1, d) with +inf sentinel row
-    buckets,  # (H, cap)
-    point_cells,  # (N+1, d) int32 cell coords, sentinel row -2
+    buckets,  # (H, cap) int32 point ids, sentinel n
+    planes,  # d arrays (H, cap) float32 coordinate planes
     origin,
     inv_cell,
     res_arr,  # (d,) int32, dynamic virtual resolution
     queries,  # (Q, d), padded queries have +inf coords
-    query_ids,  # (Q,) int32 index of query in `points`, or N for "no self"
+    query_ids,  # (Q,) int32 index of query in the points, or n for "no self"
     r2,  # scalar squared radius
     *,
+    n: int,
     table_size: int,
     k: int,
     chunk: int,
 ):
-    d = points_padded.shape[1]
+    d = len(planes)
     offs = jnp.asarray(stencil_offsets(d))  # (S, d)
 
     q_total = queries.shape[0]
@@ -137,8 +155,8 @@ def _round_impl(
     def one_chunk(carry, inp):
         q, qid = inp  # (chunk, d), (chunk,)
         top_d, top_i, found, valid = _chunk_candidates(
-            points_padded, buckets, point_cells, origin, inv_cell, res_arr,
-            offs, q, qid, r2, table_size=table_size, k=k,
+            buckets, planes, origin, inv_cell, res_arr,
+            offs, q, qid, r2, n=n, table_size=table_size, k=k,
         )
         tests = jnp.sum(valid, dtype=jnp.float32)  # distance evals this chunk
         return carry, (top_d, top_i, found, tests)
@@ -155,7 +173,6 @@ def _round_impl(
 
 
 def fixed_radius_round(
-    points,
     grid: Grid,
     queries,
     query_ids,
@@ -164,10 +181,12 @@ def fixed_radius_round(
     *,
     chunk: int = 2048,
 ):
-    """One fixed-radius search round (host wrapper; shapes made chunk-aligned).
+    """One fixed-radius search round over ``grid``'s points (host wrapper;
+    shapes made chunk-aligned).
 
     Returns (dists2 (Q,k), idxs (Q,k), found (Q,), n_tests scalar).
     Entries beyond the in-radius neighbor set have dist=inf, idx=N.
+    ``round_slots(Q, chunk, d, grid.cap)`` is the slots it gathers.
     """
     q = jnp.asarray(queries, jnp.float32)
     qid = jnp.asarray(query_ids, jnp.int32)
@@ -177,17 +196,16 @@ def fixed_radius_round(
     if pad:
         q = jnp.concatenate([q, jnp.full((pad, q.shape[1]), jnp.inf, q.dtype)])
         qid = jnp.concatenate([qid, jnp.full((pad,), grid.n_points, qid.dtype)])
-    pts = _pad_points(jnp.asarray(points, jnp.float32))
     d2, idx, found, tests = _round_impl(
-        pts,
         grid.buckets,
-        grid.point_cells,
+        grid.planes,
         grid.origin,
         grid.inv_cell,
         grid.res_arr,
         q,
         qid,
         jnp.float32(radius) ** 2,
+        n=grid.n_points,
         table_size=grid.table_size,
         k=int(k),
         chunk=chunk,

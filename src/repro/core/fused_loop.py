@@ -48,7 +48,7 @@ import numpy as np
 from repro import trace
 
 from .brute import _brute_impl
-from .fixed_radius import _chunk_candidates, _pad_points, round_chunk
+from .fixed_radius import _chunk_candidates, round_chunk, round_slots
 from .grid import _next_pow2, stencil_offsets
 
 __all__ = ["FusedSchedule", "FusedResult", "build_schedule", "fused_search"]
@@ -94,7 +94,9 @@ class FusedResult:
 
     ``dists`` are true L2 (sqrt applied on device); ``unresolved`` is the
     pre-tail mask (rows the while-loop could not resolve); ``tests[t]``
-    counts candidate distance evaluations charged to round t;
+    counts candidate distance evaluations charged to round t and
+    ``slots[t]`` the candidate slots it gathered (its live rows padded to
+    its chunk, times 3^d·cap; computed on the host);
     ``n_executed`` is how many scheduled rounds actually ran before the
     on-device predicate cleared.
     """
@@ -105,6 +107,7 @@ class FusedResult:
     unresolved: np.ndarray  # (Q,) bool, pre-tail
     resolved_round: np.ndarray  # (Q,) int32, -1 = never in-loop
     tests: np.ndarray  # (n_sched,) float64
+    slots: np.ndarray  # (n_sched,) int64, 0 for rounds that did not run
     n_executed: int
     q_pad: int
 
@@ -205,14 +208,13 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
     branch_lookup = jnp.asarray(np.asarray(branch_of, np.int32))
 
     @jax.named_scope("trueknn.fused")
-    def run(pts_padded, grids, q, qid, r2s):
-        n = pts_padded.shape[0] - 1
-        d = pts_padded.shape[1]
+    def run(pts, grids, q, qid, r2s):
+        n, d = pts.shape
         q_pad = q.shape[0]
         offs = jnp.asarray(stencil_offsets(d))
 
         def make_branch(b):
-            buckets, point_cells, origin, inv_cell, res_arr = grids[b]
+            buckets, planes, origin, inv_cell, res_arr = grids[b]
             table_size = branch_tables[b]
             cb = round_chunk(chunk, d, buckets.shape[1])
 
@@ -224,9 +226,9 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
                 def one_chunk(rows, live, state):
                     bd, bi, fd, tests = state
                     top_d2, top_i, fnd, valid = _chunk_candidates(
-                        pts_padded, buckets, point_cells, origin, inv_cell,
-                        res_arr, offs, q[rows], qid[rows], r2,
-                        table_size=table_size, k=k,
+                        buckets, planes, origin, inv_cell, res_arr, offs,
+                        q[rows], qid[rows], r2,
+                        n=n, table_size=table_size, k=k,
                     )
                     # only still-unresolved rows are charged (resolved and
                     # padding rows never reach the host driver's kernel)
@@ -288,7 +290,7 @@ def _fused_fn(branch_tables: tuple, branch_of: tuple, has_tail: bool,
             def tail_chunk_fn(rows, live, state):
                 bd_, bi_ = state
                 d2t, it = _brute_impl(
-                    pts_padded[:n], q[rows], qid[rows], k=k,
+                    pts, q[rows], qid[rows], k=k,
                     chunk=tail_chunk, exclude_self=True, metric="l2",
                 )
                 bd_ = bd_.at[rows].set(
@@ -330,7 +332,7 @@ def fused_search(points, schedule: FusedSchedule, queries, query_ids,
                 [qid, jnp.full((q_pad - q_total,),
                                schedule.grids[0].n_points, qid.dtype)]
             )
-        pts = _pad_points(jnp.asarray(points, jnp.float32))
+        pts = jnp.asarray(points, jnp.float32)
 
         # dedupe repeated grids (post-lattice-cap rounds share the
         # single-cell grid) into switch branches; the round->branch map is
@@ -346,7 +348,7 @@ def fused_search(points, schedule: FusedSchedule, queries, query_ids,
                 branch_grids.append(g)
             branch_of.append(b)
         grid_args = tuple(
-            (g.buckets, g.point_cells, g.origin, g.inv_cell, g.res_arr)
+            (g.buckets, g.planes, g.origin, g.inv_cell, g.res_arr)
             for g in branch_grids
         )
         # host numpy f32 square == device f32 square (same IEEE multiply)
@@ -363,13 +365,24 @@ def fused_search(points, schedule: FusedSchedule, queries, query_ids,
         bd, bi, found, unres, res_round, tests, t = fn(
             pts, grid_args, q, qid, r2s)
     with trace.span("trueknn.fetch"):
+        res_round = np.array(res_round[:q_total])
+        unres = np.array(unres[:q_total])
+        n_executed = int(t)
+        # round t searched the rows it had not resolved yet: those that
+        # resolved at t or later, and those the loop never resolved
+        slots = np.zeros((len(schedule.radii),), np.int64)
+        for r in range(n_executed):
+            live = int(np.sum(res_round >= r)) + int(np.sum(unres))
+            slots[r] = round_slots(live, chunk, q.shape[1],
+                                   schedule.grids[r].cap)
         return FusedResult(
             dists=np.array(bd[:q_total]),
             idxs=np.array(bi[:q_total]),
             found=np.array(found[:q_total]),
-            unresolved=np.array(unres[:q_total]),
-            resolved_round=np.array(res_round[:q_total]),
+            unresolved=unres,
+            resolved_round=res_round,
             tests=np.asarray(tests, np.float64),
-            n_executed=int(t),
+            slots=slots,
+            n_executed=n_executed,
             q_pad=q_pad,
         )

@@ -10,10 +10,16 @@ A *dense* cell array collapses on real point clouds (LiDAR: a dense core plus
 far outliers stretches the bounding box so a radius-matched dense grid needs
 billions of cells).  We therefore use a **spatial hash grid** (Teschner-style):
 virtual resolution is radius-matched and unbounded, occupied cells hash into a
-table of O(#occupied) buckets, and exactness is preserved by storing each
-point's integer cell coords and filtering gathered candidates on an exact
-coord match (the integer-compare plays the role of the hardware ray-AABB
-test; hash collisions are filtered, never double-counted).
+table of O(#occupied) buckets, and exactness is preserved by filtering
+gathered candidates on an exact match of their integer cell coords,
+recomputed from the candidates' own coordinates (the integer-compare plays
+the role of the hardware ray-AABB test; hash collisions are filtered, never
+double-counted).
+
+Bucket contents are stored bucket-major: next to the ``(H, cap)`` point ids
+each grid holds one ``(H, cap)`` float32 coordinate plane per axis, laid
+out slot for slot like the ids, so a round reads every word it needs as
+contiguous bucket rows instead of gathering point by point.
 
 Binning is a counting sort (O(N)), which plays the role of the paper's BVH
 *refit* when the radius grows.  Buckets are fixed-capacity ``(H, cap)`` with
@@ -47,7 +53,9 @@ class Grid:
 
     Attributes:
       buckets:     (H, cap) int32 point indices, padded with N (sentinel).
-      point_cells: (N+1, d) int32 cell coords per point; sentinel row = -2.
+      planes:      d arrays (H, cap) float32, one per axis: the coordinates
+                   of the bucketed points, ``planes[a][h, s] ==
+                   points[buckets[h, s], a]``; +inf in sentinel slots.
       origin:      (d,) float32 lower corner of the bounding box.
       inv_cell:    (d,) float32 reciprocal effective cell size per axis.
       res:         (d,) host ints — virtual cells per axis (bounds check only).
@@ -59,7 +67,7 @@ class Grid:
     """
 
     buckets: jax.Array
-    point_cells: jax.Array
+    planes: tuple
     origin: jax.Array
     inv_cell: jax.Array
     res: tuple
@@ -105,13 +113,12 @@ def cell_coords_of(points, origin, inv_cell, res_arr):
 
 @jax.jit
 def _bucket_order(points, origin, inv_cell, res_arr, table_size, n_valid):
-    """Stable order of the points by hash bucket, the sorted bucket ids, and
-    the points' cell coords.
+    """Stable order of the points by hash bucket, and the sorted bucket ids.
 
     Rows >= n_valid are padding (sharded grids pad shards to equal length):
-    they sort last and their cell coords are -2 (match nothing).  The table
-    size and n_valid are traced, so the one sort of the cloud compiles once
-    for every grid over it (a TPU compile of a 2^20-row sort takes ~20 s).
+    they sort last.  The table size and n_valid are traced, so the one sort
+    of the cloud compiles once for every grid over it (a TPU compile of a
+    2^20-row sort takes ~20 s).
     """
     n = points.shape[0]
     valid = jnp.arange(n) < n_valid
@@ -120,18 +127,16 @@ def _bucket_order(points, origin, inv_cell, res_arr, table_size, n_valid):
     )
     h = jnp.where(valid, hash_coords(coords, table_size), table_size - 1)
     order = jnp.argsort(h).astype(jnp.int32)  # stable
-    coords = jnp.where(valid[:, None], coords, -2)
-    sentinel = jnp.full((1, points.shape[1]), -2, jnp.int32)
-    point_cells = jnp.concatenate([coords, sentinel], axis=0)
-    return order, h[order], point_cells
+    return order, h[order]
 
 
 @partial(jax.jit, static_argnames=("table_size", "cap"))
-def _fill_buckets(order, sorted_h, n_valid, *, table_size, cap):
-    """Lay the bucket-sorted points into ``(table_size, cap)`` slots, and
-    return the fullest bucket's population, so the caller can prove no
-    point was dropped for want of a slot."""
-    n = order.shape[0]
+def _fill_buckets(order, sorted_h, points, n_valid, *, table_size, cap):
+    """Lay the bucket-sorted points into ``(table_size, cap)`` slots: their
+    ids, and their coordinates as one plane per axis in the same slots.
+    Also return the fullest bucket's population, so the caller can prove
+    no point was dropped for want of a slot."""
+    n, d = points.shape
     i = jnp.arange(n, dtype=jnp.int32)
     # rank within own bucket: distance from the bucket's first sorted row
     slot = i - jnp.searchsorted(sorted_h, sorted_h, side="left").astype(
@@ -141,11 +146,19 @@ def _fill_buckets(order, sorted_h, n_valid, *, table_size, cap):
     # ascending and unique while every bucket fits its cap; padding rows
     # land past the table and are dropped
     pos = jnp.where(real, sorted_h * cap + slot, table_size * cap + i)
-    buckets = jnp.full((table_size * cap,), n, jnp.int32).at[pos].set(
-        order, mode="drop", indices_are_sorted=True, unique_indices=True
-    )
+
+    def scatter(fill, values):
+        return jnp.full((table_size * cap,), fill, values.dtype).at[pos].set(
+            values, mode="drop", indices_are_sorted=True, unique_indices=True
+        ).reshape(table_size, cap)
+
+    buckets = scatter(n, order)
+    sorted_pts = points[order]
+    # one array per axis: an (H, cap, d) array would pad d to 128 lanes on
+    # a TPU, and slicing one (d, H, cap) array inside a round copies it
+    planes = tuple(scatter(jnp.inf, sorted_pts[:, a]) for a in range(d))
     fullest = jnp.max(jnp.where(real, slot, -1)) + 1
-    return buckets.reshape(table_size, cap), fullest
+    return buckets, planes, fullest
 
 
 def build_grid(
@@ -248,11 +261,12 @@ def build_grid(
     origin = jnp.asarray(lo)
     inv_cell = jnp.asarray(np.float32(1) / cell)
     res_arr = jnp.asarray(res_t, jnp.int32)
-    order, sorted_h, point_cells = _bucket_order(
-        jnp.asarray(pts_all), origin, inv_cell, res_arr, table_size, n_valid
+    pts_dev = jnp.asarray(pts_all)
+    order, sorted_h = _bucket_order(
+        pts_dev, origin, inv_cell, res_arr, table_size, n_valid
     )
-    buckets, fullest = _fill_buckets(
-        order, sorted_h, n_valid, table_size=table_size, cap=cap
+    buckets, planes, fullest = _fill_buckets(
+        order, sorted_h, pts_dev, n_valid, table_size=table_size, cap=cap
     )
     if int(fullest) > cap:
         raise RuntimeError(
@@ -261,7 +275,7 @@ def build_grid(
         )
     return Grid(
         buckets=buckets,
-        point_cells=point_cells,
+        planes=planes,
         origin=origin,
         inv_cell=inv_cell,
         res=res_t,

@@ -62,6 +62,9 @@ class RoundStats:
     the host loop's wall time for the round; the fused driver runs every
     round in one program and reports 0.0 (a profiler trace holds each
     round's device time under the named scope ``trueknn.round.b<b>``).
+    ``n_slots`` is the candidate slots the round gathered (its query rows
+    padded to its chunk, times 3^d·cap), of which ``n_tests`` were scored;
+    0 where not counted (the brute tail).
     """
 
     round_idx: int
@@ -73,6 +76,7 @@ class RoundStats:
     grid_cap: int
     seconds: float
     cache_hit: bool = False
+    n_slots: int = 0
 
 
 @dataclasses.dataclass

@@ -422,12 +422,11 @@ def lower_trueknn_cell(multi_pod: bool, engine: str = "dense"):
         nl, d = kcfg.n_points, kcfg.dim
         table = 1 << 21  # ~2x load factor at 1M pts/shard
         cap = 16
-        fn = make_grid_round(mesh, kcfg.k, table, chunk=1024)
+        fn = make_grid_round(mesh, kcfg.k, table, nl, chunk=1024)
         gsh = NamedSharding(mesh, P("model"))
         args = (
-            jax.ShapeDtypeStruct((p_size, nl + 1, d), jnp.float32),
             jax.ShapeDtypeStruct((p_size, table, cap), jnp.int32),
-            jax.ShapeDtypeStruct((p_size, nl + 1, d), jnp.int32),
+            (jax.ShapeDtypeStruct((p_size, table, cap), jnp.float32),) * d,
             jax.ShapeDtypeStruct((p_size, d), jnp.float32),
             jax.ShapeDtypeStruct((p_size, d), jnp.float32),
             jax.ShapeDtypeStruct((p_size, d), jnp.int32),
@@ -438,7 +437,7 @@ def lower_trueknn_cell(multi_pod: bool, engine: str = "dense"):
         jfn = jax.jit(
             fn,
             in_shardings=(
-                gsh, gsh, gsh, gsh, gsh, gsh,
+                gsh, gsh, gsh, gsh, gsh,
                 NamedSharding(mesh, P(batch_axes, None)),
                 NamedSharding(mesh, P(batch_axes)),
                 NamedSharding(mesh, P()),

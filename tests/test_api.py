@@ -194,9 +194,9 @@ def test_brute_equivalent_round_falls_through_to_brute(monkeypatch):
     real_round = tk.fixed_radius_round
     calls = {"n": 0}
 
-    def never_resolves(pts, grid, q, qid, r, k, **kw):
+    def never_resolves(grid, q, qid, r, k, **kw):
         calls["n"] += 1
-        d2, idx, found, tests = real_round(pts, grid, q, qid, r, k, **kw)
+        d2, idx, found, tests = real_round(grid, q, qid, r, k, **kw)
         return d2, idx, np.zeros_like(np.asarray(found)), tests
 
     monkeypatch.setattr(tk, "fixed_radius_round", never_resolves)

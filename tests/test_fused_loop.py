@@ -320,8 +320,8 @@ def test_grid_over_budget_folds_table_not_cells():
     assert folded.res == full.res
     assert folded.table_size < full.table_size
     q, qid = pts[:200], np.arange(200, dtype=np.int32)
-    a = fixed_radius_round(pts, full, q, qid, 0.5, 6)
-    b = fixed_radius_round(pts, folded, q, qid, 0.5, 6)
+    a = fixed_radius_round(full, q, qid, 0.5, 6)
+    b = fixed_radius_round(folded, q, qid, 0.5, 6)
     assert np.array_equal(a[0], b[0]) and np.array_equal(a[2], b[2])
 
 

@@ -87,18 +87,18 @@ def test_grid_round_compiles_at_2_20_points(one_chip):
     d, cap, table, q = 3, 32, 1 << 20, 2048
     chunk = round_chunk(2048, d, cap)
     compiled = _round_impl.lower(
-        _shape(one_chip, (N + 1, d), jnp.float32),
         _shape(one_chip, (table, cap), jnp.int32),
-        _shape(one_chip, (N + 1, d), jnp.int32),
+        (_shape(one_chip, (table, cap), jnp.float32),) * d,
         _shape(one_chip, (d,), jnp.float32),
         _shape(one_chip, (d,), jnp.float32),
         _shape(one_chip, (d,), jnp.int32),
         _shape(one_chip, (q, d), jnp.float32),
         _shape(one_chip, (q,), jnp.int32),
         _shape(one_chip, (), jnp.float32),
-        table_size=table, k=8, chunk=chunk,
+        n=N, table_size=table, k=8, chunk=chunk,
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert _candidate_gathers_are_bucket_rows(compiled.as_text(), "")
 
 
 def test_grid_binning_compiles_at_2_20_points(one_chip):
@@ -121,6 +121,7 @@ def test_grid_binning_compiles_at_2_20_points(one_chip):
         _fill_buckets.lower(
             _shape(one_chip, (N,), jnp.int32),
             _shape(one_chip, (N,), jnp.int32),
+            _shape(one_chip, (N, d), jnp.float32),
             _shape(one_chip, (), jnp.int32),
             table_size=table, cap=cap,
         ).compile()
@@ -145,35 +146,83 @@ def test_fused_loop_compiles_for_a_kitti_schedule(one_chip):
     """The one-dispatch TrueKNN program for the first rounds of a 2^20-point
     LiDAR schedule (grid shapes as ``build_grid`` sizes them there), one
     512-query serving batch, with the exact brute tail."""
-    import jax.numpy as jnp
-
     from repro.core.fused_loop import _fused_fn
 
-    d = 3
     grids = ((1 << 21, 8), (1 << 21, 16), (1 << 20, 32), (1 << 18, 128))
     fn = _fused_fn(
         tuple(t for t, _ in grids), tuple(range(len(grids))), True, 8,
         512, 512,
     )
+    compiled = _compile_fused(one_chip, fn, grids, 512)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+    assert _candidate_gathers_are_bucket_rows(compiled.as_text())
+
+
+def test_fused_loop_compiles_for_the_frame_cell_schedule(one_chip):
+    """The program the benchmark's frame cell runs: 8,192 query rows, the
+    five lattice grids ``build_grid`` sizes for the 2^20-point HDL-64E map
+    (radii 0.059 to 0.946 m, each at the 2^25-slot bucket budget), 2,048-row
+    chunks and the exact brute tail."""
+    from repro.core.fused_loop import _fused_fn
+
+    grids = ((1 << 19, 64), (1 << 17, 256), (1 << 15, 1024), (1 << 14, 2048),
+             (1 << 12, 8192))
+    fn = _fused_fn(
+        tuple(t for t, _ in grids), tuple(range(len(grids))), True, 8,
+        2048, 512,
+    )
+    compiled = _compile_fused(one_chip, fn, grids, 8192)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+    assert _candidate_gathers_are_bucket_rows(compiled.as_text())
+
+
+def _compile_fused(one_chip, fn, grids, rows):
+    import jax.numpy as jnp
+
+    d = 3
     grid_args = tuple(
         (
             _shape(one_chip, (t, cap), jnp.int32),
-            _shape(one_chip, (N + 1, d), jnp.int32),
+            (_shape(one_chip, (t, cap), jnp.float32),) * d,
             _shape(one_chip, (d,), jnp.float32),
             _shape(one_chip, (d,), jnp.float32),
             _shape(one_chip, (d,), jnp.int32),
         )
         for t, cap in grids
     )
-    compiled = fn.lower(
-        _shape(one_chip, (N + 1, d), jnp.float32),
+    return fn.lower(
+        _shape(one_chip, (N, d), jnp.float32),
         grid_args,
-        _shape(one_chip, (512, d), jnp.float32),
-        _shape(one_chip, (512,), jnp.int32),
+        _shape(one_chip, (rows, d), jnp.float32),
+        _shape(one_chip, (rows,), jnp.int32),
         _shape(one_chip, (len(grids),), jnp.float32),
     ).compile()
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+
+
+def _candidate_gathers_are_bucket_rows(hlo: str, scope="trueknn.round"):
+    """Every gather in ``scope`` that yields a block of candidates (the 27
+    stencil cells by the query rows by the slots) fetches whole bucket
+    rows (``slice_sizes={1,cap}``): no candidate's point or cell is
+    gathered by id, element by element."""
+    import re
+
+    found = 0
+    for line in hlo.splitlines():
+        m = re.search(
+            r"= \w+\[([\d,]+)\]\S* gather\(.*slice_sizes=\{([\d,]+)\}", line
+        )
+        if not m or scope not in line:
+            continue
+        shape = [int(x) for x in m.group(1).split(",")]
+        sizes = [int(x) for x in m.group(2).split(",")]
+        if len(shape) < 3:
+            continue  # per-row gathers: queries, ids, the best-k lists
+        found += 1
+        if len(shape) != 3 or 27 not in shape[:2] or sizes != [1, shape[-1]]:
+            return False
+    return found > 0
 
 
 def test_placed_fused_rounds_compile_for_four_chips(topo):
