@@ -17,6 +17,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.brute import brute_knn_engine
 from repro.core.result import KNNResult, RangeResult
 
@@ -32,7 +33,8 @@ __all__ = ["BruteIndex"]
 class BruteIndex(NeighborIndex):
     """Exact kNN by chunked dense distances.
 
-    cfg: ``chunk`` (query tile, default 512).
+    cfg: ``chunk`` (query tile, default 512).  ``stats()["pair_tests"]``
+    counts the query-point pairs the kNN engine scored (sum of Q·N).
     """
 
     native_metrics = frozenset({"l2", "l1", "linf", "cosine"})
@@ -43,15 +45,18 @@ class BruteIndex(NeighborIndex):
         self._chunk = int(chunk)
         self._pts_j = jnp.asarray(self._pts)  # device-resident for the life
         self._queries_served = 0
+        self._pair_tests = 0  # query-point pairs the dense engine scored
 
     def _knn(self, queries, k: int, metric: Metric, *, cut=None):
         t0 = time.perf_counter()
-        d, i, n_tests = brute_knn_engine(
-            self._pts_j, k, queries=queries, chunk=self._chunk,
-            metric=metric.kernel_name,
-        )
-        dists = np.asarray(d)
-        idxs = np.asarray(i)
+        with trace.span("brute.dispatch"):
+            d, i, n_tests = brute_knn_engine(
+                self._pts_j, k, queries=queries, chunk=self._chunk,
+                metric=metric.kernel_name,
+            )
+        with trace.span("brute.fetch"):
+            dists = np.asarray(d)
+            idxs = np.asarray(i)
         found = None
         if cut is not None:
             # radius cap: drop beyond-radius hits.  NOTE: the engine only
@@ -64,6 +69,7 @@ class BruteIndex(NeighborIndex):
                 dists, idxs, cut, self.n_points
             )
         self._queries_served += dists.shape[0]
+        self._pair_tests += int(n_tests)
         return KNNResult(
             dists=dists,
             idxs=idxs,
@@ -100,4 +106,5 @@ class BruteIndex(NeighborIndex):
     def stats(self) -> dict:
         s = super().stats()
         s["queries_served"] = self._queries_served
+        s["pair_tests"] = self._pair_tests
         return s

@@ -21,7 +21,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.fixed_radius import fixed_radius_round
-from repro.core.grid import build_grid
+from repro.core.grid import build_grid, check_grid_dim
 from repro.core.result import KNNResult, RoundStats
 
 from ..index import NeighborIndex
@@ -48,6 +48,7 @@ class FixedRadiusIndex(NeighborIndex):
     def __init__(self, points, *, radius: Optional[float] = None,
                  chunk: int = 2048, max_cached_grids: int = 16):
         super().__init__(points)
+        check_grid_dim(self.dim, 'backend "fixed_radius"')
         self._default_radius = radius
         self._chunk = int(chunk)
         self._max_cached_grids = max(1, int(max_cached_grids))

@@ -45,7 +45,7 @@ from repro.core.fixed_radius import (
     round_slots,
 )
 from repro.core.fused_loop import build_schedule, fused_search
-from repro.core.grid import _next_pow2, build_grid
+from repro.core.grid import _next_pow2, build_grid, check_grid_dim
 from repro.core.result import KNNResult, RoundStats
 from repro.core.sampling import sample_start_radius
 
@@ -111,6 +111,7 @@ class TrueKNNIndex(NeighborIndex):
         fused: bool = True,
     ):
         super().__init__(points)
+        check_grid_dim(self.dim, 'backend "trueknn"')
         assert growth > 1.0, "radius growth factor must exceed 1"
         self._pts_j = jnp.asarray(self._pts)
         self._growth = float(growth)

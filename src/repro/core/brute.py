@@ -24,6 +24,35 @@ from repro.kernels.ops import l2_normalize
 __all__ = ["brute_knn", "brute_knn_engine"]
 
 
+@partial(jax.jit, static_argnames=("metric",))
+def _chunk_dists(points, p_norm2, q, *, metric):
+    """(chunk, N) distances of one query chunk: squared L2, or raw L1/Linf.
+
+    A jit of its own so that, traced inside a scope of the chunk loop, the
+    reducer regions of its sums carry no scope: JAX names a region by the
+    loop body's own scope path, without the callers' prefixes, and the
+    fused program's readers expect every scope in it to nest under theirs.
+    """
+    d = points.shape[1]
+    if metric in ("l1", "linf"):
+        # raw metric distances — no squaring, no sqrt downstream
+        ad = jnp.abs(q[:, None, :] - points[None, :, :])
+        return jnp.sum(ad, axis=-1) if metric == "l1" else jnp.max(ad, -1)
+    if d <= 8:
+        # exact diff-based form: the matmul identity loses ~1e-7 absolute
+        # to cancellation, which is catastrophic for the tiny squared
+        # distances of tightly-clustered data (and d<=8 never profits
+        # from the MXU anyway)
+        diff = q[:, None, :] - points[None, :, :]
+        return jnp.sum(diff * diff, axis=-1)
+    q_norm2 = jnp.sum(q * q, axis=-1)
+    # HIGHEST: at the default precision a TPU rounds the operands of a
+    # float32 matmul to bfloat16, a 2^-9 relative error per query
+    # coordinate in the cross term; the distances are stated in float32
+    cross = jnp.matmul(q, points.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(q_norm2[:, None] + p_norm2[None, :] - 2.0 * cross, 0.0)
+
+
 @partial(jax.jit, static_argnames=("k", "chunk", "exclude_self", "metric"))
 def _brute_impl(points, queries, query_ids, *, k, chunk, exclude_self, metric):
     n = points.shape[0]
@@ -34,24 +63,13 @@ def _brute_impl(points, queries, query_ids, *, k, chunk, exclude_self, metric):
 
     def one_chunk(_, inp):
         q, qid = inp
-        if metric in ("l1", "linf"):
-            # raw metric distances — no squaring, no sqrt downstream
-            ad = jnp.abs(q[:, None, :] - points[None, :, :])
-            d2 = jnp.sum(ad, axis=-1) if metric == "l1" else jnp.max(ad, -1)
-        elif d <= 8:
-            # exact diff-based form: the matmul identity loses ~1e-7 absolute
-            # to cancellation, which is catastrophic for the tiny squared
-            # distances of tightly-clustered data (and d<=8 never profits
-            # from the MXU anyway)
-            diff = q[:, None, :] - points[None, :, :]
-            d2 = jnp.sum(diff * diff, axis=-1)
-        else:
-            q_norm2 = jnp.sum(q * q, axis=-1)
-            d2 = q_norm2[:, None] + p_norm2[None, :] - 2.0 * (q @ points.T)
-            d2 = jnp.maximum(d2, 0.0)
-        if exclude_self:
-            d2 = jnp.where(jnp.arange(n)[None, :] == qid[:, None], jnp.inf, d2)
-        neg, idx = jax.lax.top_k(-d2, k)
+        with jax.named_scope("brute.dist"):
+            d2 = _chunk_dists(points, p_norm2, q, metric=metric)
+            if exclude_self:
+                d2 = jnp.where(jnp.arange(n)[None, :] == qid[:, None],
+                               jnp.inf, d2)
+        with jax.named_scope("brute.select"):
+            neg, idx = jax.lax.top_k(-d2, k)
         return None, (-neg, idx)
 
     qs = queries.reshape(-1, chunk, d)

@@ -36,10 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Grid", "build_grid", "stencil_offsets", "hash_coords"]
+__all__ = ["Grid", "build_grid", "check_grid_dim", "stencil_offsets",
+           "hash_coords"]
 
 # Teschner et al. spatial-hash primes (one per axis).
 _HASH_PRIMES = (73856093, 19349663, 83492791)
+#: most axes a grid can hash: one prime per axis
+MAX_GRID_DIM = len(_HASH_PRIMES)
 _MAX_RES_PER_AXIS = 1 << 20  # keeps packed host-side ids within int64
 
 
@@ -76,6 +79,17 @@ class Grid:
     cap: int
     n_points: int
     cell_size: np.ndarray
+
+
+def check_grid_dim(d: int, engine: str) -> None:
+    """Refuse, at build time, a cloud of more axes than a grid hashes;
+    ``engine`` names the index being built, for the message."""
+    if d > MAX_GRID_DIM:
+        raise ValueError(
+            f"{engine} hashes points into a grid of at most {MAX_GRID_DIM} "
+            f"dimensions, and these points have {d}; use "
+            f'backend="brute" (exact dense kNN) for d > {MAX_GRID_DIM}'
+        )
 
 
 def stencil_offsets(d: int) -> np.ndarray:

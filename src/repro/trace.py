@@ -25,13 +25,19 @@ the same thread:
   trueknn.dispatch     upload, padding and enqueue of the fused program
   trueknn.fetch        waiting for the device and copying results back
   trueknn.finish       rounds, warm-start EMA and ``KNNResult`` on the host
+  brute.dispatch       the brute backend's kNN: padding, upload and enqueue
+                       of the dense engine (``core.brute._brute_impl``)
+  brute.fetch          waiting for the device and copying its results back
   server.batch         one coalesced batch of ``NeighborServer``
   server.execute       the part of that batch that holds the index
 
 The fused program carries device-side names as ``jax.named_scope``
 metadata, which costs nothing at run time: ``trueknn.fused`` around the
 whole program, ``trueknn.round.b<b>`` around the ops of grid branch ``b``
-and ``trueknn.tail`` around the exact brute tail.
+and ``trueknn.tail`` around the exact brute tail.  The dense engine
+(``_brute_impl``) names its distance block ``brute.dist`` and its top-k
+``brute.select``; inside the fused program they nest under
+``trueknn.tail``.
 
 Counters stay with the layer that owns them (``index.stats()``,
 ``server.stats()``); this module records time only.
@@ -46,7 +52,7 @@ import jax
 __all__ = ["PREFIXES", "enable", "enabled", "span"]
 
 #: name prefixes of the program's spans, for readers of a trace
-PREFIXES = ("index.", "trueknn.", "server.")
+PREFIXES = ("index.", "trueknn.", "brute.", "server.")
 
 _on = False
 _OFF = contextlib.nullcontext()

@@ -142,6 +142,31 @@ def test_brute_engine_compiles_at_2_20_points(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def test_brute_engine_compiles_for_the_sift_cell(one_chip):
+    """The dense cell's whole engine: 10^6 descriptors of 128 dimensions,
+    10,000 queries padded to 20 chunks of 512, k=10.  Its cross term is a
+    float32 (HIGHEST) matmul, and its temp memory is about one (512, 10^6)
+    float32 distance block (2.05 GB)."""
+    import jax.numpy as jnp
+
+    from repro.core.brute import _brute_impl
+
+    n, d, q = 10**6, 128, 10240
+    compiled = _brute_impl.lower(
+        _shape(one_chip, (n, d), jnp.float32),
+        _shape(one_chip, (q, d), jnp.float32),
+        _shape(one_chip, (q,), jnp.int32),
+        k=10, chunk=512, exclude_self=False, metric="l2",
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+    text = compiled.as_text()
+    # the TPU compiler writes the matmul as a convolution
+    (cross,) = [ln for ln in text.splitlines()
+                if "brute.dist/" in ln and "dot_general" in ln
+                and ("convolution(" in ln or " dot(" in ln)]
+    assert "operand_precision={highest,highest}" in cross
+
+
 def test_fused_loop_compiles_for_a_kitti_schedule(one_chip):
     """The one-dispatch TrueKNN program for the first rounds of a 2^20-point
     LiDAR schedule (grid shapes as ``build_grid`` sizes them there), one
